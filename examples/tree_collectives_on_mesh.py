@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import Communicator
 from repro.core.topology import tpu_v5e_multipod
 
@@ -27,7 +28,7 @@ for r, edges in enumerate(plan.rounds):
     lv = [topo.levels[topo.comm_level(s, d)].name for s, d in edges]
     print(f"  round {r}: {edges}  links={lv}")
 
-mesh = jax.make_mesh((8,), ("all",))
+mesh = make_mesh((8,), ("all",))
 x = jnp.arange(8.0)
 
 bcast = jax.jit(shard_map(lambda v: comm.bcast(v, root=3),

@@ -241,8 +241,6 @@ def device_probes(*, sizes: Sequence[float] = DEFAULT_PROBE_SIZES,
     import jax.numpy as jnp
     from jax import lax
 
-    from repro.compat import shard_map
-
     devices = list(devices if devices is not None else jax.devices())
     P = len(devices)
     if P < 2:
@@ -266,7 +264,7 @@ def device_probes(*, sizes: Sequence[float] = DEFAULT_PROBE_SIZES,
                         return lax.ppermute(u, "probe", bwd)
                     return lax.fori_loop(0, roundtrips, body, v)
 
-                f = jax.jit(shard_map(bounce, mesh=mesh, in_specs=spec,
+                f = jax.jit(jax.shard_map(bounce, mesh=mesh, in_specs=spec,
                                       out_specs=spec))
                 jax.block_until_ready(f(x))  # compile + warm
                 best = math.inf

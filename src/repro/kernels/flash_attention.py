@@ -8,7 +8,9 @@ VMEM scratch across its steps.
 GQA layout (shared by forward and backward): q is (B*Hkv, G*bq, hd) blocks
 against k/v (B*Hkv, bk, hd) — the query-group dim rides inside the q block
 so one k/v VMEM stage serves all G query heads of its group (cuts k/v HBM
-traffic by G).
+traffic by G).  Per-row statistics (lse, delta) travel as one (1, G*bq) row
+per (b, q block): Mosaic cannot split a 1-D lane vector into a (G, bq) tile,
+so the kernels never reshape them beyond that row.
 
 Backward = blockwise recompute (no S x S buffer):
   delta_i = rowsum(do_i * o_i)                       (precomputed, tiny)
@@ -39,6 +41,9 @@ from repro.kernels.backend import resolve_interpret
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
+# rows (G*block_q) of one q block: the (rows, block_k) f32 score and
+# probability tiles must fit v5e's 16 MiB scoped VMEM — 4096 rows overflow
+MAX_BLOCK_ROWS = 2048
 NEG_INF = -1e30
 
 
@@ -102,7 +107,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         l = jnp.maximum(l_ref[...], 1e-30)
         o = acc_ref[...] / l[:, None]
         o_ref[0] = o.reshape(G_, bq, hd).astype(o_ref.dtype)
-        lse_ref[0] = (m_ref[...] + jnp.log(l)).reshape(G_, bq)
+        lse_ref[0] = (m_ref[...] + jnp.log(l)).reshape(1, G_ * bq)
 
 
 def _fold_gqa(q, k, v):
@@ -117,8 +122,25 @@ def _fold_gqa(q, k, v):
     return qg, kg, vg
 
 
-def _check_blocks(Sq, Sk, block_q, block_k):
+def _rows_to_blocks(x, n_q, block_q):
+    """(N, G, Sq) per-row stats -> (N*n_q, 1, G*block_q): one kernel row per
+    (n, q block), holding all G groups of that block."""
+    N, G, _ = x.shape
+    return (x.reshape(N, G, n_q, block_q).transpose(0, 2, 1, 3)
+            .reshape(N * n_q, 1, G * block_q))
+
+
+def _blocks_to_rows(x, N, G, n_q, block_q):
+    """Inverse of :func:`_rows_to_blocks`."""
+    return (x.reshape(N, n_q, G, block_q).transpose(0, 2, 1, 3)
+            .reshape(N, G, n_q * block_q))
+
+
+def _check_blocks(Sq, Sk, block_q, block_k, G):
     block_q, block_k = min(block_q, Sq), min(block_k, Sk)
+    # halving keeps a divisor of Sq a divisor
+    while G * block_q > MAX_BLOCK_ROWS and block_q % 256 == 0:
+        block_q //= 2
     if Sq % block_q != 0 or Sk % block_k != 0:
         raise ValueError(f"flash attention blocks must tile the "
                          f"sequence: Sq={Sq} Sk={Sk} "
@@ -143,7 +165,7 @@ def flash_attention_fwd(
     B, Sq, H, hd = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv
-    block_q, block_k = _check_blocks(Sq, Sk, block_q, block_k)
+    block_q, block_k = _check_blocks(Sq, Sk, block_q, block_k, G)
     n_q, n_k = Sq // block_q, Sk // block_k
     scale = 1.0 / math.sqrt(hd)
     qg, kg, vg = _fold_gqa(q, k, v)
@@ -161,11 +183,13 @@ def flash_attention_fwd(
         ],
         out_specs=[
             pl.BlockSpec((1, G, block_q, hd), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, G, block_q), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 1, G * block_q),
+                         lambda b, i, j: (b * n_q + i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Hkv, G, Sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((B * Hkv, G, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B * Hkv * n_q, 1, G * block_q),
+                                 jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((G * block_q,), jnp.float32),   # running max m
@@ -176,6 +200,7 @@ def flash_attention_fwd(
     )(qg, kg, vg)
     # (B*Hkv, G, Sq, hd) -> (B, Sq, H, hd)
     out = out.reshape(B, Hkv, G, Sq, hd).transpose(0, 3, 1, 2, 4)
+    lse = _blocks_to_rows(lse, B * Hkv, G, n_q, block_q)
     return out.reshape(B, Sq, H, hd), lse.reshape(B, Hkv, G, Sq)
 
 
@@ -278,7 +303,7 @@ def flash_attention_bwd(
     B, Sq, H, hd = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv
-    block_q, block_k = _check_blocks(Sq, Sk, block_q, block_k)
+    block_q, block_k = _check_blocks(Sq, Sk, block_q, block_k, G)
     n_q, n_k = Sq // block_q, Sk // block_k
     scale = 1.0 / math.sqrt(hd)
     interpret = resolve_interpret(interpret)
@@ -286,16 +311,18 @@ def flash_attention_bwd(
     qg, kg, vg = _fold_gqa(q, k, v)
     dog, _, _ = _fold_gqa(do, k, v)
     og, _, _ = _fold_gqa(o, k, v)
-    lseg = lse.reshape(B * Hkv, G, Sq)
+    lseg = _rows_to_blocks(lse.reshape(B * Hkv, G, Sq), n_q, block_q)
     # delta_i = rowsum(do_i * o_i): O(S*hd), cheap enough to precompute
-    delta = jnp.einsum("bgsd,bgsd->bgs", dog.astype(jnp.float32),
-                       og.astype(jnp.float32))
+    delta = _rows_to_blocks(
+        jnp.einsum("bgsd,bgsd->bgs", dog.astype(jnp.float32),
+                   og.astype(jnp.float32)), n_q, block_q)
 
     kw = dict(scale=scale, causal=causal, window=window, block_q=block_q,
               block_k=block_k, q_offset=q_offset)
     q_spec = pl.BlockSpec((1, G, block_q, hd), lambda b, i, j: (b, 0, i, 0))
     kv_spec = pl.BlockSpec((1, block_k, hd), lambda b, i, j: (b, j, 0))
-    row_spec = pl.BlockSpec((1, G, block_q), lambda b, i, j: (b, 0, i))
+    row_spec = pl.BlockSpec((1, 1, G * block_q),
+                            lambda b, i, j: (b * n_q + i, 0, 0))
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, n_k=n_k, **kw),
@@ -311,7 +338,8 @@ def flash_attention_bwd(
     # dkv grid swaps the loop order: index maps see (b, j, i)
     q_spec_t = pl.BlockSpec((1, G, block_q, hd), lambda b, j, i: (b, 0, i, 0))
     kv_spec_t = pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b, j, 0))
-    row_spec_t = pl.BlockSpec((1, G, block_q), lambda b, j, i: (b, 0, i))
+    row_spec_t = pl.BlockSpec((1, 1, G * block_q),
+                              lambda b, j, i: (b * n_q + i, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, n_q=n_q, **kw),
         grid=(B * Hkv, n_k, n_q),

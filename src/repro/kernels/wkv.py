@@ -15,6 +15,10 @@ across steps; this version also removes the per-chunk block re-staging).
 
 Validated against ``models.layers._wkv_chunk_scan`` in
 tests/test_kernels.py; ``interpret=None`` auto-detects the backend.
+
+Interpret mode only: the TPU compiler refuses this kernel (``jnp.cumsum``
+has no Pallas TPU lowering), and no model calls it — rwkv6 runs
+``_wkv_chunk_scan``.
 """
 from __future__ import annotations
 

@@ -1,8 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("_DRYRUN_EXTRA_XLA", "") +
                            " --xla_force_host_platform_device_count=512").strip()
-# Persistent compilation cache makes re-sweeps (perf iterations) cheap.
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
+# Persistent compilation cache makes re-sweeps (perf iterations) cheap: where
+# JAX_COMPILATION_CACHE_DIR says, else one fixed directory in the checkout.
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "..", ".jax_cache")))
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 
@@ -28,8 +30,6 @@ import traceback
 
 import numpy as np
 import jax
-
-from repro import compat
 
 from repro.configs import get_config, list_archs, SHAPES
 from repro.configs.shapes import input_specs, cache_specs, applicable
@@ -92,7 +92,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         fn = jax.jit(raw, donate_argnums=(0, 1),
                      in_shardings=(p_sh, o_sh,
                                    jax.tree.map(lambda _: b_sh, batch)))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(aparams, aopt, batch)
             compiled = lowered.compile()
     elif shape.kind == "prefill":
@@ -103,7 +103,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         batch = input_specs(cfg, shape)
         fn = jax.jit(raw,
                      in_shardings=(p_sh, jax.tree.map(lambda _: b_sh, batch)))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(aparams, batch)
             compiled = lowered.compile()
     else:  # decode
@@ -118,7 +118,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         fn = jax.jit(raw, donate_argnums=(1,),
                      in_shardings=(p_sh, c_sh, tok_sh,
                                    NamedSharding(mesh, P())))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(aparams, acache, inp["tokens"],
                                inp["pos"])
             compiled = lowered.compile()

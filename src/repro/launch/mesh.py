@@ -9,22 +9,33 @@ per step.  No tensor-parallel collective ever crosses a pod boundary.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_test_mesh", "mesh_topology",
-           "dp_topology", "dp_decomposition", "mesh_communicator"]
+__all__ = ["make_mesh", "make_production_mesh", "make_test_mesh",
+           "mesh_topology", "dp_topology", "dp_decomposition",
+           "mesh_communicator"]
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the model code relies on
+    GSPMD propagation (``jax.make_mesh`` defaults to ``Explicit`` axes, under
+    which its gathers, sorts and ragged dots refuse to trace)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(pods: int = 1, data: int = 2, model: int = 2):
-    """Small mesh for CPU tests (requires xla_force_host_platform_device_count)."""
+    """(pod, data, model) mesh over the first ``pods*data*model`` devices;
+    the pod axis appears only when ``pods > 1``."""
     if pods > 1:
-        return jax.make_mesh((pods, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pods, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_topology(mesh) -> "object":

@@ -1,9 +1,12 @@
 """Serving driver: continuous batching over a paged KV cache, with the
 multilevel engine pricing per-request collectives against weight broadcasts.
 
-CPU demo:
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
-  PYTHONPATH=src python -m repro.launch.serve --arch gpt-100m --requests 4
+One device (a TPU chip, or the CPU):
+  PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \\
+      --full-config --prompt-len 512
+CPU demo on four virtual devices:
+  XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
+  PYTHONPATH=src python -m repro.launch.serve --arch gpt-100m --mesh 1x2x2
 """
 from __future__ import annotations
 
@@ -12,8 +15,6 @@ import time
 
 import numpy as np
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 
 from repro.configs import get_config
@@ -66,7 +67,7 @@ def _engine_demo(wcomm, wbytes: float, cfg, prompt_len: int, model: int,
 
 
 def serve(arch: str, n_requests: int, prompt_len: int, gen_len: int,
-          mesh_spec: str = "1x2x2", smoke: bool = True, *,
+          mesh_spec: str = "1x1x1", smoke: bool = True, *,
           policy: str = "priority", block_size: int = 8,
           rate: float | None = None, trace: str | None = None,
           monitor: bool = False, metrics_out: str | None = None) -> dict:
@@ -79,7 +80,8 @@ def serve(arch: str, n_requests: int, prompt_len: int, gen_len: int,
     ``monitor`` attaches a :class:`~repro.obs.HealthMonitor` to the engine
     (drift detection + auto-refit, periodic health snapshots in the log);
     ``metrics_out`` writes the run's Prometheus text exposition — a
-    scrape-file path that needs no tracer at all."""
+    scrape-file path that needs no tracer at all.  The result carries the
+    ``executor`` so callers can inspect its compiled programs and state."""
     cfg = get_config(arch, smoke=smoke)
     pods, data, model = (int(x) for x in mesh_spec.split("x"))
     mesh = make_test_mesh(pods, data, model)
@@ -149,7 +151,7 @@ def serve(arch: str, n_requests: int, prompt_len: int, gen_len: int,
                          gen_len=gen_len, slo=SLO(), seed=0)
 
     t0 = time.monotonic()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         report = sch.run(reqs)
     dt = time.monotonic() - t0
     gen = np.stack([np.asarray(r.tokens, np.int32)
@@ -187,7 +189,7 @@ def serve(arch: str, n_requests: int, prompt_len: int, gen_len: int,
                  event="trace", path=trace, events=tracer.n_events())
     out = {"generated": gen, "seconds": dt,
            "tokens_per_s": n_requests * gen_len / dt,
-           "report": s}
+           "report": s, "executor": ex}
     if mon is not None:
         out["health"] = mon.snapshot()
     return out
@@ -199,7 +201,9 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen-len", type=int, default=16)
-    ap.add_argument("--mesh", default="1x2x2")
+    ap.add_argument("--mesh", default="1x1x1")
+    ap.add_argument("--full-config", action="store_true",
+                    help="use the full (non-smoke) architecture config")
     ap.add_argument("--policy", default="priority",
                     choices=("fifo", "priority", "slo"))
     ap.add_argument("--rate", type=float, default=None,
@@ -220,7 +224,8 @@ def main() -> None:
     args = ap.parse_args()
     set_json(args.log_json)
     out = serve(args.arch, args.requests, args.prompt_len, args.gen_len,
-                args.mesh, policy=args.policy, rate=args.rate,
+                args.mesh, smoke=not args.full_config,
+                policy=args.policy, rate=args.rate,
                 trace=args.trace, monitor=args.monitor,
                 metrics_out=args.metrics_out)
     log.info(f"generated {out['generated'].shape} tokens in "

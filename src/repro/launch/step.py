@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.models import transformer as T
 from repro.models import sharding as SH
 from repro.models.config import ModelConfig
@@ -123,7 +121,7 @@ def make_train_fn(cfg: ModelConfig, opt_cfg: adamw.OptConfig, mesh,
 
     if model_axis:
         # nested shard_map: mesh inferred from the enclosing manual context
-        update = shard_map(update,
+        update = jax.shard_map(update,
                            in_specs=(pspecs, pspecs, opt_inner),
                            out_specs=(pspecs, opt_inner),
                            axis_names={"model"}, check_vma=False)
@@ -135,7 +133,7 @@ def make_train_fn(cfg: ModelConfig, opt_cfg: adamw.OptConfig, mesh,
         return new_params, new_opt, lax.pmean(loss_val, dp)
 
     batch_spec = P(dp)
-    return shard_map(
+    return jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P(), opt_specs, batch_spec),

@@ -1,8 +1,11 @@
 """Training driver: checkpoint/restart, elastic recovery, straggler
 mitigation — the control plane the dry-run's data plane plugs into.
 
-Usage (CPU demo, also the e2e example driver):
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+Usage (one device, a TPU chip or the CPU):
+  PYTHONPATH=src python -m repro.launch.train --arch gpt-100m --steps 5 \\
+      --full-config --seq 2048 --batch 8
+CPU demo on four virtual devices (also the e2e example driver):
+  XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
   PYTHONPATH=src python -m repro.launch.train --arch gpt-100m --steps 200 \\
       --mesh 1x2x2 --seq 128 --batch 8 --comm multilevel
 
@@ -88,7 +91,8 @@ def train(arch: str, steps: int, mesh_spec: str, seq: int, batch: int,
     (reverse-layer order, one fused collective per bucket — overlappable
     with backward); forces the dense optimizer state since ZeRO-1 scatters
     per leaf.  ``trace`` writes a Chrome trace of the simulated planning
-    plane (per-link occupancy, planner decisions) to that path."""
+    plane (per-link occupancy, planner decisions) to that path.  The
+    summary's ``params`` are the final device-resident parameters."""
     cfg = get_config(arch, smoke=smoke)
     shape = ShapeSpec("custom", "train", seq, batch)
     mesh = build_mesh(mesh_spec)
@@ -282,14 +286,15 @@ def train(arch: str, steps: int, mesh_spec: str, seq: int, batch: int,
     return {"losses": losses, "recoveries": recoveries,
             "repairs": repairs,
             "stragglers": len(straggler.dropped_steps),
-            "final_loss": losses[-1] if losses else None}
+            "final_loss": losses[-1] if losses else None,
+            "params": params}
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt-100m")
     ap.add_argument("--steps", type=int, default=100)
-    ap.add_argument("--mesh", default="1x2x2")
+    ap.add_argument("--mesh", default="1x1x1")
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--comm", default="multilevel",
@@ -297,7 +302,7 @@ def main() -> None:
     ap.add_argument("--no-zero1", action="store_true")
     ap.add_argument("--full-config", action="store_true",
                     help="use the full (non-smoke) architecture config")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default="checkpoints")
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--bucket-mb", type=float, default=0.0,
                     help="size-targeted gradient buckets (MiB); 0 = one "

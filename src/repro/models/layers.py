@@ -253,6 +253,59 @@ def _flash_vjp_bwd(causal, window, cq, ck, q_offset, res, do):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def _pallas_flash(q, k, v, causal, window, chunk_q, chunk_k, q_offset):
+    """The Pallas flash kernel, inside a shard_map when the caller left any
+    mesh axis ``Auto``: Mosaic kernels cannot be partitioned by GSPMD.  The
+    shard_map names every axis, the already-manual ones too — a nested
+    shard_map that names only the auto axes still lowers as partially
+    automatic.  Heads split over ``model`` and batch over auto dp axes where
+    they divide; anything else is replicated.
+
+    Forward and backward kernels each run in their own shard_map under one
+    custom VJP, so autodiff never transposes the shard_map: with
+    ``check_vma=False`` that transpose psums the input cotangents over every
+    axis the specs leave out, already-manual dp axes included, which would
+    mix the gradients of different batch shards."""
+    from repro.kernels import flash_attention as fa
+
+    am = jax.sharding.get_abstract_mesh()
+    auto = {n for n, t in zip(am.axis_names, am.axis_types)
+            if t != jax.sharding.AxisType.Manual}
+    if not auto:
+        return fa.flash_attention(q, k, v, causal, window, chunk_q, chunk_k,
+                                  q_offset, None)
+    B, H, Hkv = q.shape[0], q.shape[2], k.shape[2]
+    dp = tuple(a for a in ("pod", "data") if a in auto)
+    dp_size = math.prod(am.shape[a] for a in dp)
+    b_ax = dp if dp and B % dp_size == 0 else None
+    h_ax = ("model" if "model" in auto and H % am.shape["model"] == 0
+            and Hkv % am.shape["model"] == 0 else None)
+    spec = jax.sharding.PartitionSpec(b_ax, None, h_ax, None)
+    lse_spec = jax.sharding.PartitionSpec(b_ax, h_ax, None, None)
+    kw = dict(causal=causal, window=window, block_q=chunk_q, block_k=chunk_k,
+              q_offset=q_offset)
+
+    def smap(f, in_specs, out_specs):
+        return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
+                             axis_names=set(am.axis_names), check_vma=False)
+
+    fwd = smap(functools.partial(fa.flash_attention_fwd, **kw), (spec,) * 3,
+               (spec, lse_spec))
+    bwd = smap(functools.partial(fa.flash_attention_bwd, **kw),
+               (spec,) * 4 + (lse_spec, spec), (spec,) * 3)
+
+    @jax.custom_vjp
+    def attend(q, k, v):
+        return fwd(q, k, v)[0]
+
+    def attend_fwd(q, k, v):
+        o, lse = fwd(q, k, v)
+        return o, (q, k, v, o, lse)
+
+    attend.defvjp(attend_fwd, lambda res, do: bwd(*res, do))
+    return attend(q, k, v)
+
+
 def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                       chunk_q: int = 512, chunk_k: int = 512,
                       q_offset: int = 0, impl: str | None = None) -> jax.Array:
@@ -274,9 +327,8 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None
         from repro.kernels.backend import on_tpu  # lazy: models stay light
         impl = "pallas" if on_tpu() else "jnp"
     if impl == "pallas":
-        from repro.kernels import flash_attention as fa
-        return fa.flash_attention(q, k, v, causal, window, chunk_q, chunk_k,
-                                  q_offset, None)
+        return _pallas_flash(q, k, v, causal, window, chunk_q, chunk_k,
+                             q_offset)
     if impl != "jnp":
         raise ValueError(f"chunked_attention impl must be None, 'pallas' or "
                          f"'jnp', got {impl!r}")
@@ -376,10 +428,8 @@ def _cache_attend_sp(q, k_new, v_new, cache_k, cache_v, pos, windowed,
 
 def _sp_decode_ctx(s_cache: int, batch: int):
     """(use_sp, auto_dp) when a model axis exists and divides the cache."""
-    import jax.sharding as jsh
-    from repro.compat import get_abstract_mesh
-    am = get_abstract_mesh()
-    if am is None or "model" not in (am.axis_names or ()):
+    am = jax.sharding.get_abstract_mesh()
+    if "model" not in am.axis_names:
         return False, ()
     msize = am.shape["model"]
     if msize <= 1 or s_cache % msize:
@@ -402,7 +452,6 @@ def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos, *,
     With a model axis present, the cache attention runs as an explicit
     flash-decode shard_map (sequence-sharded cache + LSE combine)."""
     import jax.sharding as jsh
-    from repro.compat import shard_map
 
     B = x.shape[0]
     hd = cfg.head_dim
@@ -422,7 +471,7 @@ def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos, *,
         bdp = auto_dp if auto_dp else None
         rep4 = P(bdp, None, None, None)
         cache_spec = P(bdp, "model", None, None)
-        sp = shard_map(
+        sp = jax.shard_map(
             lambda qq, kn, vn, ckk, cvv, pp: _cache_attend_sp(
                 qq, kn, vn, ckk, cvv, pp, windowed),
             in_specs=(rep4, rep4, rep4, cache_spec, cache_spec, P()),
@@ -687,7 +736,6 @@ def moe_fwd(p, cfg: ModelConfig, x, chunk: int = MOE_CHUNK) -> jax.Array:
     Long sequences are scanned in token blocks with remat: dispatch buffers
     live only per block (8x working-set cut at olmoe prefill_32k)."""
     import jax.sharding as jsh
-    from repro.compat import shard_map
 
     m = cfg.moe
     B, S, D = x.shape
@@ -695,9 +743,8 @@ def moe_fwd(p, cfg: ModelConfig, x, chunk: int = MOE_CHUNK) -> jax.Array:
     xt = x.reshape(T, D)
 
     block = None
-    from repro.compat import get_abstract_mesh
-    am = get_abstract_mesh()
-    if am is not None and "model" in (am.axis_names or ()):
+    am = jsh.get_abstract_mesh()
+    if "model" in am.axis_names:
         msize = am.shape["model"]
         if msize > 1 and m.n_experts % msize == 0:
             # dp axes still in AUTO state (e.g. the GSPMD serving path) must
@@ -719,7 +766,7 @@ def moe_fwd(p, cfg: ModelConfig, x, chunk: int = MOE_CHUNK) -> jax.Array:
             if m.shared_expert:
                 especs["shared"] = jax.tree.map(
                     lambda _: jsh.PartitionSpec(), p["shared"])
-            ep = shard_map(
+            ep = jax.shard_map(
                 lambda pp, xb: _moe_block_ep(pp, m, xb, "model"),
                 in_specs=(especs, tok_spec),
                 out_specs=tok_spec,

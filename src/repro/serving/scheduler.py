@@ -142,7 +142,8 @@ class JaxExecutor:
         import functools
         self._decode = jax.jit(functools.partial(T.decode_step_paged, cfg=cfg))
 
-    def _prefill_fn(self, S_p: int):
+    def prefill_fn(self, S_p: int):
+        """The jitted prefill program for padded length ``S_p``."""
         fn = self._prefills.get(S_p)
         if fn is None:
             import jax
@@ -159,7 +160,7 @@ class JaxExecutor:
         S_p = len(blocks) * self.block_size
         padded = np.zeros((1, S_p), np.int32)
         padded[0, :L] = tokens
-        logits, cache, _ = self._prefill_fn(S_p)(
+        logits, cache, _ = self.prefill_fn(S_p)(
             self.params, jnp.asarray(padded), jnp.asarray([L - 1]))
         self.pools = self._T.scatter_prefill_cache(
             self.pools, cache, list(blocks), self.block_size, row=0)

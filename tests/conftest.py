@@ -29,18 +29,8 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 560) -> str:
     Multi-device collective tests must not pollute the main pytest process
     (which keeps the default 1-device view per the project brief).
     """
-    import jax
-
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
-    # persistent compile cache: repeat suite runs skip the expensive jits.
-    # Gated to modern jax: on 0.4.x a warm cache mis-serves the donated-
-    # buffer train step (loss 0.0 -> nan on the second suite run, correct
-    # when compiled fresh), so there the cache must stay off.
-    if hasattr(jax, "shard_map"):
-        env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache_tests")
-        env.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
-        env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     # Deliberately do NOT forward -O / PYTHONOPTIMIZE: pytest's assertion
     # rewriting protects only in-process test modules, so optimizing the

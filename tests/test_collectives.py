@@ -1,19 +1,15 @@
 """Multi-device collective tests (subprocess with 8 host devices)."""
-import jax
 import pytest
-
-# The ZeRO-1 train path nests a mesh-less shard_map inside a manual region,
-# which needs the modern mesh-context API (jax.shard_map).
-NESTED_SHARD_MAP = hasattr(jax, "shard_map")
 
 
 def test_multilevel_psum_equals_flat(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.core.collectives import multilevel_psum_tree
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 grads = {"w": jnp.arange(24., dtype=jnp.float32).reshape(4, 6),
          "b": jnp.ones((3,))}
 def sync(mode):
@@ -35,10 +31,11 @@ def test_bucketed_psum_tree_matches_monolithic(subproc):
     result for every bucket size, in flat and multilevel modes."""
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.core.collectives import bucketed_psum_tree, multilevel_psum_tree
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 grads = {"w": jnp.arange(24., dtype=jnp.float32).reshape(4, 6),
          "b": jnp.ones((3,)), "c": [jnp.full((5,), 2.0),
                                     jnp.arange(7., dtype=jnp.float32)]}
@@ -70,10 +67,11 @@ def test_bucketed_apply_updates_matches_dense(subproc):
     parameters as the per-leaf dense path."""
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.optim import adamw
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 params = {"w": jnp.arange(32., dtype=jnp.float32).reshape(8, 4) / 32,
           "b": jnp.ones((8,), jnp.float32)}
 grads = {"w": jnp.full((8, 4), 0.25, jnp.float32),
@@ -134,11 +132,12 @@ def test_error_feedback_corrects_compressed_drift(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core.compression import (apply_error_feedback, compressed_psum,
                                     quantize_int8, dequantize_int8)
 
-mesh = jax.make_mesh((2,), ("pod",))
+mesh = make_mesh((2,), ("pod",))
 N = 512
 rng = np.random.default_rng(0)
 g_host = rng.normal(size=(2, N)).astype(np.float32) * 1e-3
@@ -186,13 +185,14 @@ def test_allreduce_tree_threads_ef(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core.collectives import compress_ef_zeros
 from repro.core.topology import tpu_v5e_multipod
 from repro.core import Communicator
 
 topo = tpu_v5e_multipod(pods=2, boards=1, chips_per_board=2)
-mesh = jax.make_mesh((2, 2), ("pod", "data"))
+mesh = make_mesh((2, 2), ("pod", "data"))
 comm = Communicator(topo, backend="jax", slow_axis="pod",
                     fast_axes=("data",))
 grads = {"w": jnp.full((4, 6), 1e-4, jnp.float32),
@@ -266,13 +266,14 @@ for zero1 in (True, False):
 def test_tree_collectives_on_devices(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.core.trees import build_multilevel_tree
 from repro.core.topology import tpu_v5e_multipod
 from repro.core import tree_exec
 topo = tpu_v5e_multipod(pods=2, boards=2, chips_per_board=2)
-mesh1 = jax.make_mesh((8,), ("all",))
+mesh1 = make_mesh((8,), ("all",))
 x = jnp.arange(8., dtype=jnp.float32)
 for root in [0, 3, 7]:
     tree = build_multilevel_tree(topo, root=root)
@@ -289,8 +290,6 @@ print("OK")
 """)
 
 
-@pytest.mark.skipif(not NESTED_SHARD_MAP,
-                    reason="nested mesh-less shard_map needs newer jax")
 def test_zero1_multilevel_trains_identically_to_flat(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
@@ -326,11 +325,6 @@ print("OK")
 """)
 
 
-@pytest.mark.skipif(not NESTED_SHARD_MAP,
-                    reason="model-sharded KV-cache decode diverges (~0.45 "
-                           "max logit err) under the legacy SPMD partitioner"
-                           " — identical program is exact unsharded; needs "
-                           "newer jax")
 def test_decode_sharded_cache(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
@@ -393,10 +387,11 @@ def test_allreduce_tree_ef_tile_padding(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core.collectives import compress_ef_zeros, multilevel_psum_tree
 
-mesh = jax.make_mesh((2, 2), ("pod", "data"))
+mesh = make_mesh((2, 2), ("pod", "data"))
 grads = {"w": jnp.full((4, 6), 1e-4, jnp.float32),
          "b": jnp.ones((7,), jnp.float32)}
 ef0 = compress_ef_zeros(grads, 2, tile=12)   # 31 -> pad to 48 -> shard 24

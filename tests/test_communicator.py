@@ -403,7 +403,8 @@ def test_backend_agreement_on_mesh(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import Communicator
 from repro.core.topology import tpu_v5e_multipod
 
@@ -412,7 +413,7 @@ ROOT = 3
 x_host = np.arange(8.0, dtype=np.float32)
 
 # --- ppermute backend: explicit tree rounds over the flat axis ---------
-mesh1 = jax.make_mesh((8,), ("all",))
+mesh1 = make_mesh((8,), ("all",))
 pp = Communicator(topo, policy="paper", backend="ppermute", axis="all")
 def run_pp(fn):
     return np.asarray(jax.jit(shard_map(
@@ -420,7 +421,7 @@ def run_pp(fn):
             jnp.asarray(x_host)))
 
 # --- jax backend: axis-decomposed shortcuts over (pod, fast) -----------
-mesh2 = jax.make_mesh((2, 4), ("pod", "fast"))
+mesh2 = make_mesh((2, 4), ("pod", "fast"))
 jx = Communicator(topo, backend="jax", slow_axis="pod", fast_axes=("fast",))
 def run_jx(fn):
     return np.asarray(jax.jit(shard_map(

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core.compression import QTILE
 from repro.kernels import ops, ref
 
 KEY = jax.random.PRNGKey(7)
@@ -69,7 +70,7 @@ def test_quant_roundtrip_error_bound(ntiles, scale):
 
 
 def test_quant_matches_reference_exactly():
-    x = jax.random.normal(KEY, (256 * 32 * 2,), jnp.float32) * 5
+    x = jax.random.normal(KEY, (QTILE * 2,), jnp.float32) * 5
     q, s, pad = ops.quantize_int8(x)
     qr, sr = ref.quantize_int8_reference(x)
     assert pad == 0
@@ -80,7 +81,7 @@ def test_quant_matches_reference_exactly():
 def test_quant_padding_path():
     x = jax.random.normal(KEY, (1000,), jnp.float32)
     q, s, pad = ops.quantize_int8(x)
-    assert pad == 256 * 32 - 1000
+    assert pad == QTILE - 1000
     xd = ops.dequantize_int8(q, s, pad)
     assert xd.shape == (1000,)
     assert float(jnp.abs(xd - x).max()) < 0.05
@@ -192,12 +193,12 @@ def test_quantize_ef_fused_bitidentical_to_two_pass(ntiles, off, scale):
     """Property: the fused kernel's (q, scales, residual) are BIT-identical
     to quantise(x+ef) / dequantise / subtract through the same kernels —
     fusion removes HBM round trips, not a single bit of the arithmetic."""
-    n = 256 * 32 * ntiles - off
+    n = QTILE * ntiles - off
     x = jax.random.normal(jax.random.fold_in(KEY, n), (n,), jnp.float32) * scale
     ef = jax.random.normal(jax.random.fold_in(KEY, n + 1), (n,), jnp.float32) * 1e-3
     qf, sf, rf, pad = ops.quantize_ef_int8(x, ef)
     q2, s2, pad2 = ops.quantize_int8(x + ef)
-    assert pad == pad2 == off % (256 * 32)
+    assert pad == pad2 == off % QTILE
     r2 = (x + ef) - ops.dequantize_int8(q2, s2, pad2)
     np.testing.assert_array_equal(np.asarray(qf), np.asarray(q2))
     np.testing.assert_array_equal(np.asarray(sf), np.asarray(s2))
@@ -209,7 +210,7 @@ def test_apply_error_feedback_kernel_matches_jnp():
     (both compute g+ef in jnp); the residual agrees to 1 ulp (the jit'd
     kernel divides by 127 via reciprocal, the eager path by true division)."""
     from repro.core import compression
-    for n in (256 * 32, 4096, 333):
+    for n in (QTILE, 4096, 333):
         g = jax.random.normal(jax.random.fold_in(KEY, n), (n,), jnp.float32)
         ef = jax.random.normal(jax.random.fold_in(KEY, n + 1), (n,), jnp.float32) * 1e-3
         gk, rk = compression.apply_error_feedback(g, ef, use_kernel=True)

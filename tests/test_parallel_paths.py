@@ -2,21 +2,14 @@
 expert-parallel MoE (nested shard_map) and flash-decode (sequence-sharded
 KV cache with LSE combine).  Both must be numerically equivalent to the
 single-device reference paths."""
-import jax
 import pytest
-
-# These paths dispatch on the ambient abstract mesh (jax.set_mesh), which
-# older toolchains do not expose — the model code falls back to the
-# reference path there, making the comparison vacuous.
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="abstract-mesh dispatch (jax.set_mesh) needs newer jax")
 
 
 def test_ep_moe_matches_reference(subproc):
     subproc("""
 import dataclasses, jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.models import layers as L
 from repro.models.sharding import param_pspecs
@@ -26,7 +19,7 @@ cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe,
 p = L.init_moe(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model), jnp.float32)
 y_ref = L.moe_fwd(p, cfg, x)                       # no mesh -> ragged path
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 psh = jax.tree.map(lambda s: NamedSharding(mesh, s), param_pspecs(p, 2),
                    is_leaf=lambda v: isinstance(v, P))
 pd = jax.device_put(p, psh)
@@ -47,6 +40,7 @@ def test_ep_moe_capacity_drops_bounded(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.models import layers as L
 from repro.models.sharding import param_pspecs
@@ -54,7 +48,7 @@ cfg = get_config("llama4_scout_17b_a16e", smoke=True)  # top-1, shared expert
 p = L.init_moe(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model), jnp.float32)
 y_ref = L.moe_fwd(p, cfg, x)
-mesh = jax.make_mesh((1, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((1, 2, 2), ("pod", "data", "model"))
 psh = jax.tree.map(lambda s: NamedSharding(mesh, s), param_pspecs(p, 2),
                    is_leaf=lambda v: isinstance(v, P))
 pd = jax.device_put(p, psh)
@@ -98,3 +92,52 @@ for arch in ["qwen3_4b", "gemma3_12b"]:   # full + sliding-window caches
                                atol=0.1, rtol=0.05)
 print("OK")
 """)
+
+
+@pytest.mark.parametrize("ctx", ["auto_mesh", "dp_manual"])
+def test_pallas_flash_partitions_over_mesh(subproc, ctx):
+    """The Pallas flash kernel under a mesh: with every axis Auto (the
+    serving path) and inside a dp-manual shard_map (the train step) it runs
+    in its own shard_map over heads and batch, and matches the jnp
+    lowering in value and gradient at a GQA shape."""
+    subproc(f"""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_test_mesh
+from repro.models.layers import chunked_attention
+mesh = make_test_mesh(pods=1, data=2, model=2)
+B, S, H, Hkv, hd = 4, 256, 8, 2, 32                  # G = 4
+ks = jax.random.split(jax.random.PRNGKey(0), 3)
+q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32)
+k = jax.random.normal(ks[1], (B, S, Hkv, hd), jnp.float32)
+v = jax.random.normal(ks[2], (B, S, Hkv, hd), jnp.float32)
+
+def attend(impl):
+    def f(q, k, v):
+        return chunked_attention(q, k, v, chunk_q=128, chunk_k=128,
+                                 impl=impl)
+    if "{ctx}" == "dp_manual":
+        f = jax.shard_map(f, in_specs=P("data"), out_specs=P("data"),
+                          axis_names={{"data"}}, check_vma=False)
+    return f
+
+def grads(impl):
+    return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(attend(impl)(q, k, v))),
+                    argnums=(0, 1, 2))
+
+with jax.set_mesh(mesh):
+    jaxpr = str(jax.make_jaxpr(attend("pallas"))(q, k, v))
+    want_spec = ("PartitionSpec('data', None, 'model', None)"
+                 if "{ctx}" == "auto_mesh"
+                 else "PartitionSpec(None, None, 'model', None)")
+    assert want_spec in jaxpr, jaxpr
+    assert "pallas_call" in jaxpr
+    op, oj = (jax.jit(attend(i))(q, k, v) for i in ("pallas", "jnp"))
+    gp, gj = (jax.jit(grads(i))(q, k, v) for i in ("pallas", "jnp"))
+assert len(op.sharding.device_set) == 4
+np.testing.assert_allclose(np.asarray(op), np.asarray(oj), atol=1e-5)
+for name, a, b in zip(("dq", "dk", "dv"), gp, gj):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                               err_msg=name)
+print("OK")
+""", n_devices=4)

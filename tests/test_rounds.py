@@ -265,7 +265,8 @@ def test_lowered_sag_rsag_on_devices(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import Communicator
 from repro.core import rounds as R
 from repro.core.topology import tpu_v5e_multipod
@@ -275,7 +276,7 @@ from repro.core.topology import tpu_v5e_multipod
 R.MIN_CHUNK_BYTES = 1.0
 
 topo = tpu_v5e_multipod(pods=2, boards=2, chips_per_board=2)
-mesh = jax.make_mesh((8,), ("all",))
+mesh = make_mesh((8,), ("all",))
 x = np.arange(8.0, dtype=np.float32)
 
 for algorithm, op, want in [("sag", "bcast", np.full(8, 3.0)),
