@@ -1,0 +1,91 @@
+"""Operations and bytes of the work a cell asks for, counted from its
+shapes: the same whatever implements it.  Taken from the analytic models of
+``benchmarks/roofline.py`` (model FLOPs over active parameters, causal
+attention counted over the pairs it needs, the head only where logits are
+read) and from the flash algorithm's own passes.
+
+A multiply-add counts two operations.  ``c`` is a configuration file.
+"""
+from __future__ import annotations
+
+
+def _dims(c: dict):
+    D, hd = c["hidden_size"], c["head_dim"]
+    return (D, c["num_attention_heads"], c["num_key_value_heads"], hd,
+            c["intermediate_size"], c["vocab_size"], c["num_hidden_layers"])
+
+
+def layer_matmul_params(c: dict) -> int:
+    """Weights one token multiplies by in one layer (active experts only)."""
+    D, H, Hkv, hd, F, _, _ = _dims(c)
+    attn = D * H * hd + 2 * D * Hkv * hd + H * hd * D
+    if c.get("num_experts"):
+        mlp = c["num_experts_per_tok"] * 3 * D * F + D * c["num_experts"]
+    else:
+        mlp = 3 * D * F
+    return attn + mlp
+
+
+def causal_pairs(S: int) -> int:
+    """(query, key) pairs a causal sequence of length S attends."""
+    return S * (S + 1) // 2
+
+
+def attention_flops(c: dict, S: int) -> float:
+    """Scores and weighted values of one layer over one sequence."""
+    _, H, _, hd, _, _, _ = _dims(c)
+    return 4.0 * H * hd * causal_pairs(S)
+
+
+def prefill_flops(c: dict, S: int) -> float:
+    """One prompt of length S: every layer, logits for the last token."""
+    D, *_, V, L = _dims(c)
+    return (2.0 * layer_matmul_params(c) * S * L
+            + attention_flops(c, S) * L + 2.0 * D * V)
+
+
+def train_step_flops(c: dict, rows: int, S: int) -> float:
+    """Forward and backward (3x forward) of ``rows`` sequences of length
+    S, logits at every position; recomputation is not counted."""
+    D, *_, V, L = _dims(c)
+    fwd = (2.0 * layer_matmul_params(c) * S * L
+           + attention_flops(c, S) * L + 2.0 * D * V * S)
+    return 3.0 * fwd * rows
+
+
+# ---------------------------------------------------------------------- #
+# The flash kernels, one call = one layer's attention over B sequences
+# ---------------------------------------------------------------------- #
+
+def _qkvo_bytes(c: dict, B: int, S: int, itemsize: int = 2) -> float:
+    _, H, Hkv, hd, _, _, _ = _dims(c)
+    return float(B * S * hd * (2 * H + 2 * Hkv) * itemsize)
+
+
+def flash_fwd(c: dict, B: int, S: int) -> tuple[float, float]:
+    """(flops, bytes): q k^T and p v; read q, k, v, write o and lse."""
+    _, H, _, _, _, _, _ = _dims(c)
+    return (B * attention_flops(c, S),
+            _qkvo_bytes(c, B, S) + 4.0 * B * H * S)
+
+
+def flash_bwd_dq(c: dict, B: int, S: int) -> tuple[float, float]:
+    """(flops, bytes): recomputed q k^T, dO v^T and dS k; reads q, k, v,
+    dO, lse and delta, writes dq."""
+    _, H, _, hd, _, _, _ = _dims(c)
+    return (1.5 * B * attention_flops(c, S),
+            _qkvo_bytes(c, B, S) + 2.0 * B * S * H * hd + 8.0 * B * H * S)
+
+
+def flash_bwd_dkv(c: dict, B: int, S: int) -> tuple[float, float]:
+    """(flops, bytes): recomputed q k^T, dO v^T, p^T dO and dS^T q; reads
+    q, k, v, dO, lse and delta, writes dk and dv."""
+    _, H, Hkv, hd, _, _, _ = _dims(c)
+    return (2.0 * B * attention_flops(c, S),
+            _qkvo_bytes(c, B, S) + 2.0 * B * S * Hkv * hd * 2
+            + 8.0 * B * H * S)
+
+
+def roofline_s(flops: float, nbytes: float, peak: dict) -> float:
+    """Least time the chip could take: the larger of the two bounds."""
+    return max(flops / peak["bf16_flops"], nbytes / peak["hbm_bytes_per_s"])
