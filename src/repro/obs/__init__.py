@@ -13,7 +13,7 @@ from .contention import deconvolve, occupancy
 from .log import get_logger, set_json
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry, percentile
 from .trace import (PID_LINKS, PID_PLANNER, PID_PROGRAMS, PID_REQUESTS,
-                    Tracer)
+                    Tracer, span)
 
 __all__ = [
     "Counter",
@@ -22,6 +22,7 @@ __all__ = [
     "MetricsRegistry",
     "percentile",
     "Tracer",
+    "span",
     "PID_LINKS",
     "PID_PROGRAMS",
     "PID_REQUESTS",

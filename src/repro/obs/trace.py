@@ -1,4 +1,5 @@
-"""Low-overhead span/event recorder with Chrome trace-event export.
+"""Low-overhead span/event recorder with Chrome trace-event export, and
+wall-clock spans on the profiler's clock.
 
 One :class:`Tracer` instance collects everything a run observes — per-link
 busy intervals from the simulators, per-handle lifecycle spans from the
@@ -30,9 +31,17 @@ Track layout (Chrome ``pid``/``tid``):
 * pid ``PID_PLANNER``  — wall-clock planner track (cache hit/miss instants
   with the selected algorithm × segment and predicted cost).
 
-All simulated tracks share the virtual clock (seconds, converted to µs at
-export); the planner track uses wall-clock µs since tracer creation.  The
-two never share a pid, so mixed units cannot mislead within one track.
+Clocks, in three tiers.  All simulated tracks share the virtual clock
+(seconds, converted to µs at export); the planner track uses wall-clock µs
+since tracer creation.  The two never share a pid, so mixed units cannot
+mislead within one track.  The third tier lives outside the
+:class:`Tracer`: :func:`span` marks a phase of the real served path
+(``repro.decode.*`` in ``JaxExecutor.decode``, ``repro.sched.*`` in
+``Scheduler.run``) as a ``jax.profiler.TraceAnnotation``.  It is recorded
+only while a profile is being taken, on the profiler's clock, so it lands
+in the same trace as the device's operations and names what the host did
+during a device gap.  The profiler being on or off is its only switch.
+Every span name starts with ``repro.``.
 """
 from __future__ import annotations
 
@@ -41,6 +50,7 @@ import time
 
 __all__ = [
     "Tracer",
+    "span",
     "PID_LINKS",
     "PID_PROGRAMS",
     "PID_REQUESTS",
@@ -60,10 +70,28 @@ _PROCESS_NAMES = {
 }
 
 
+_TraceAnnotation = None
+
+
+def span(name: str):
+    """A wall-clock span named ``name`` (``repro.<layer>.<phase>``) in the
+    profiler's trace: a context manager that is
+    ``jax.profiler.TraceAnnotation(name)``.  Records nothing unless a
+    profile is being taken; never syncs with the device."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
+
+
 class Tracer:
-    """Append-only event sink.  One instance per run; pass it as the
+    """Append-only sink of virtual-clock links, spans and instants and of
+    planner wall-clock instants.  One instance per run; pass it as the
     ``tracer=`` keyword down through Communicator → Engine → simulator →
-    Scheduler and call :meth:`to_chrome` / :meth:`save` at the end."""
+    Scheduler and call :meth:`to_chrome` / :meth:`save` at the end.  The
+    served path's wall-clock phases are :func:`span`'s, in the profiler's
+    trace, not this sink's."""
 
     def __init__(self, defer: bool = True):
         # (src, dst, level, t0, t1, nbytes, kind, first, label,
@@ -77,8 +105,6 @@ class Tracer:
         self.spans: list[tuple] = []
         # (pid, key, name, t, args_or_None)
         self.instants: list[tuple] = []
-        # (name, value) monotonic tallies surfaced as trace metadata
-        self.counters: dict[str, float] = {}
         # With ``defer`` (the default) the simulators record NOTHING on
         # their hot paths: they queue a zero-arg replay closure via
         # :meth:`defer_record` and the deterministic re-execution happens
@@ -126,9 +152,6 @@ class Tracer:
     def wall(self) -> float:
         """Seconds since tracer creation — timestamps for PID_PLANNER."""
         return time.perf_counter() - self._wall0
-
-    def count(self, name: str, n: float = 1) -> None:
-        self.counters[name] = self.counters.get(name, 0) + n
 
     def defer_record(self, fn) -> None:
         """Queue a zero-arg closure that records into this tracer when the
@@ -233,10 +256,7 @@ class Tracer:
             meta.append({"name": "thread_name", "ph": "M", "pid": pid,
                          "tid": t, "args": {"name": track_name}})
 
-        doc = {"traceEvents": meta + events, "displayTimeUnit": "ms"}
-        if self.counters:
-            doc["otherData"] = {"counters": dict(sorted(self.counters.items()))}
-        return doc
+        return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
 
     def save(self, path: str) -> None:
         with open(path, "w") as f:

@@ -38,7 +38,7 @@ from typing import Protocol, Sequence
 import numpy as np
 
 from ..obs.metrics import MetricsRegistry, percentile
-from ..obs.trace import PID_REQUESTS
+from ..obs.trace import PID_REQUESTS, span
 from .kv_cache import BlockAllocator, blocks_needed
 from .loadgen import Request, ReqState
 
@@ -177,17 +177,23 @@ class JaxExecutor:
 
     def decode(self, slots, tokens, pos):
         jnp = self._jnp
-        tok = np.zeros((self.max_slots, 1), np.int32)
-        posv = np.zeros((self.max_slots,), np.int32)
-        for s, t, p in zip(slots, tokens, pos):
-            tok[s, 0] = t
-            posv[s] = p
-        logits, self.pools = self._decode(
-            params=self.params, pools=self.pools,
-            block_tables=jnp.asarray(self.tables), tokens=jnp.asarray(tok),
-            pos=jnp.asarray(posv))
-        out = np.asarray(jnp.argmax(logits[:, 0], -1))
-        return [int(out[s]) for s in slots]
+        with span("repro.decode.inputs"):
+            tok = np.zeros((self.max_slots, 1), np.int32)
+            posv = np.zeros((self.max_slots,), np.int32)
+            for s, t, p in zip(slots, tokens, pos):
+                tok[s, 0] = t
+                posv[s] = p
+            tables = jnp.asarray(self.tables)
+            tok, posv = jnp.asarray(tok), jnp.asarray(posv)
+        with span("repro.decode.launch"):
+            logits, self.pools = self._decode(
+                params=self.params, pools=self.pools,
+                block_tables=tables, tokens=tok, pos=posv)
+        with span("repro.decode.sample"):
+            nxt = jnp.argmax(logits[:, 0], -1)
+        with span("repro.decode.readback"):
+            out = np.asarray(nxt)
+            return [int(out[s]) for s in slots]
 
     def release(self, slot):
         self.tables[slot, :] = 0
@@ -395,73 +401,81 @@ class Scheduler:
         admit_s: dict[int, float] = {}  # rid -> admission time (spans)
 
         while pending or waiting or running:
-            while pending and pending[0].arrival_s <= now:
-                waiting.append(pending.popleft())
-            if not waiting and not running:
-                now = pending[0].arrival_s
-                continue
-
-            prefill_tokens, admitted = self._admit(waiting, running, now)
-            if tr is not None:
-                for r in admitted:
-                    admit_s[r.rid] = now
-                    if now > r.arrival_s:
-                        tr.span(PID_REQUESTS, f"req{r.rid}", "waiting",
-                                r.arrival_s, now)
-            if not running and waiting:
-                # nothing runs and the head request can't ever be admitted
-                # (every block is free right now): fail loudly, don't spin
-                raise RuntimeError(
-                    f"request {waiting[0].rid} needs more memory/slots than "
-                    f"the scheduler has (capacity {self.alloc.capacity} "
-                    f"blocks, {self.max_slots} slots)")
-            max_conc = max(max_conc, len(running))
-
-            # decode plane: requests already holding a first token
-            deciding, stalled = [], []
-            for r in running:
-                if r.state is not ReqState.DECODE:
+            # host work of the step outside the executor calls, in three
+            # phases on the profiler's clock; the calls sit between price
+            # and retire
+            with span("repro.sched.admit"):
+                while pending and pending[0].arrival_s <= now:
+                    waiting.append(pending.popleft())
+                if not waiting and not running:
+                    now = pending[0].arrival_s
                     continue
-                need = blocks_needed(r.pos + 1, self.block_size)
-                if need > len(r.blocks):
-                    if need > self.max_blocks:
-                        raise RuntimeError(f"request {r.rid} overran s_max")
-                    if self.alloc.can_alloc(1):
-                        blk = self.alloc.alloc(1)[0]
-                        r.blocks.append(blk)
-                        self.ex.extend(r.slot, blk)
-                    else:
-                        stalled.append(r)   # OOM: skip this step, retry
-                        r.stalled_steps += 1
-                        continue
-                deciding.append(r)
-            stalls += len(stalled)
-            if stalled:
-                self._m_stalled.inc(len(stalled))
-            if stalled and not deciding and not admitted:
-                # every live request is OOM-stalled: nobody will ever free a
-                # block.  Evict the youngest to break the deadlock (its
-                # blocks recycle into the survivors).
-                victim = max(stalled, key=lambda r: r.arrival_s)
-                victim.state = ReqState.SHED
-                victim.finish_s = now
-                self._m_shed.inc()
-                if self.monitor is not None:
-                    self.monitor.observe_request(victim, evicted=True)
-                if tr is not None:
-                    tr.instant(PID_REQUESTS, f"req{victim.rid}", "evicted",
-                               now, {"reason": "OOM deadlock, youngest "
-                                               "victim recycled"})
-                self.alloc.free(victim.blocks)
-                victim.blocks = []
-                self.ex.release(victim.slot)
-                victim.slot = -1
-                running.remove(victim)
-                continue
 
-            compute_s = self.compute_model(prefill_tokens, len(deciding))
-            net_s = self._network_step(running, now, step)
-            now += max(compute_s, net_s)
+                prefill_tokens, admitted = self._admit(waiting, running, now)
+                if tr is not None:
+                    for r in admitted:
+                        admit_s[r.rid] = now
+                        if now > r.arrival_s:
+                            tr.span(PID_REQUESTS, f"req{r.rid}", "waiting",
+                                    r.arrival_s, now)
+                if not running and waiting:
+                    # nothing runs and the head request can't ever be
+                    # admitted (every block is free right now): fail
+                    # loudly, don't spin
+                    raise RuntimeError(
+                        f"request {waiting[0].rid} needs more memory/slots "
+                        f"than the scheduler has (capacity "
+                        f"{self.alloc.capacity} blocks, {self.max_slots} "
+                        f"slots)")
+                max_conc = max(max_conc, len(running))
+
+                # decode plane: requests already holding a first token
+                deciding, stalled = [], []
+                for r in running:
+                    if r.state is not ReqState.DECODE:
+                        continue
+                    need = blocks_needed(r.pos + 1, self.block_size)
+                    if need > len(r.blocks):
+                        if need > self.max_blocks:
+                            raise RuntimeError(
+                                f"request {r.rid} overran s_max")
+                        if self.alloc.can_alloc(1):
+                            blk = self.alloc.alloc(1)[0]
+                            r.blocks.append(blk)
+                            self.ex.extend(r.slot, blk)
+                        else:
+                            stalled.append(r)   # OOM: skip this step, retry
+                            r.stalled_steps += 1
+                            continue
+                    deciding.append(r)
+                stalls += len(stalled)
+                if stalled:
+                    self._m_stalled.inc(len(stalled))
+                if stalled and not deciding and not admitted:
+                    # every live request is OOM-stalled: nobody will ever
+                    # free a block.  Evict the youngest to break the
+                    # deadlock (its blocks recycle into the survivors).
+                    victim = max(stalled, key=lambda r: r.arrival_s)
+                    victim.state = ReqState.SHED
+                    victim.finish_s = now
+                    self._m_shed.inc()
+                    if self.monitor is not None:
+                        self.monitor.observe_request(victim, evicted=True)
+                    if tr is not None:
+                        tr.instant(PID_REQUESTS, f"req{victim.rid}", "evicted",
+                                   now, {"reason": "OOM deadlock, youngest "
+                                                   "victim recycled"})
+                    self.alloc.free(victim.blocks)
+                    victim.blocks = []
+                    self.ex.release(victim.slot)
+                    victim.slot = -1
+                    running.remove(victim)
+                    continue
+
+            with span("repro.sched.price"):
+                compute_s = self.compute_model(prefill_tokens, len(deciding))
+                net_s = self._network_step(running, now, step)
+                now += max(compute_s, net_s)
 
             # commit tokens at the step's completion time
             for r in admitted:
@@ -483,29 +497,30 @@ class Scheduler:
                     r.tokens.append(int(t))
                     r.pos += 1
 
-            for r in list(running):
-                if len(r.tokens) >= r.max_new_tokens:
-                    r.state = ReqState.DONE
-                    r.finish_s = now
-                    self._m_done.inc()
-                    if r.ttft is not None:
-                        self._m_ttft.observe(r.ttft)
-                    if r.tpot is not None:
-                        self._m_tpot.observe(r.tpot)
-                    if self.monitor is not None:
-                        self.monitor.observe_request(r)
-                    if tr is not None:
-                        tr.span(PID_REQUESTS, f"req{r.rid}", "decode",
-                                r.first_token_s, now,
-                                {"tokens": len(r.tokens),
-                                 "ttft_s": r.ttft, "tpot_s": r.tpot})
-                    self.alloc.free(r.blocks)
-                    r.blocks = []
-                    self.ex.release(r.slot)
-                    r.slot = -1
-                    running.remove(r)
-            step += 1
-            if self.monitor is not None:
-                self.monitor.on_step(now, step)
+            with span("repro.sched.retire"):
+                for r in list(running):
+                    if len(r.tokens) >= r.max_new_tokens:
+                        r.state = ReqState.DONE
+                        r.finish_s = now
+                        self._m_done.inc()
+                        if r.ttft is not None:
+                            self._m_ttft.observe(r.ttft)
+                        if r.tpot is not None:
+                            self._m_tpot.observe(r.tpot)
+                        if self.monitor is not None:
+                            self.monitor.observe_request(r)
+                        if tr is not None:
+                            tr.span(PID_REQUESTS, f"req{r.rid}", "decode",
+                                    r.first_token_s, now,
+                                    {"tokens": len(r.tokens),
+                                     "ttft_s": r.ttft, "tpot_s": r.tpot})
+                        self.alloc.free(r.blocks)
+                        r.blocks = []
+                        self.ex.release(r.slot)
+                        r.slot = -1
+                        running.remove(r)
+                step += 1
+                if self.monitor is not None:
+                    self.monitor.on_step(now, step)
 
         return ServeReport(requests, step, now, max_conc, stalls)

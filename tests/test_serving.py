@@ -354,13 +354,10 @@ def test_paged_arch_check_rejects_stateful_stacks(arch):
         T.init_paged_pools(cfg, 8, 4)
 
 
-def test_scheduler_jax_executor_greedy_equivalence():
-    """End to end: the continuous-batching scheduler over the real paged
-    executor must emit, per request, exactly the greedy tokens of a
-    standalone dense prefill+decode loop — with variable prompt lengths,
-    staggered finishes, and slots being recycled mid-run."""
+def _small_jax_serve():
+    """Three requests of variable prompt lengths and staggered finishes
+    for the real paged executor on two slots, so slots recycle mid-run."""
     cfg = get_config("qwen3_4b", smoke=True)
-    params_key = jax.random.PRNGKey(0)
     BS, s_max = 4, 24
     prompts = [3, 8, 5]                  # padded lengths 4 / 8 / 8
     gens = [6, 3, 5]                     # staggered finishes recycle slots
@@ -375,6 +372,16 @@ def test_scheduler_jax_executor_greedy_equivalence():
                      max_slots=2, max_blocks=s_max // BS, seed=0)
     sch = Scheduler(ex, n_blocks=1 + 2 * (s_max // BS), block_size=BS,
                     max_slots=2, s_max=s_max, prefill_token_budget=16)
+    return cfg, ex, sch, reqs
+
+
+def test_scheduler_jax_executor_greedy_equivalence():
+    """End to end: the continuous-batching scheduler over the real paged
+    executor must emit, per request, exactly the greedy tokens of a
+    standalone dense prefill+decode loop — with variable prompt lengths,
+    staggered finishes, and slots being recycled mid-run."""
+    cfg, _, sch, reqs = _small_jax_serve()
+    params_key = jax.random.PRNGKey(0)
     rep = sch.run(reqs)
     assert all(r.state is ReqState.DONE for r in reqs)
     assert rep.max_concurrent == 2       # slots recycled across 3 requests
@@ -391,3 +398,38 @@ def test_scheduler_jax_executor_greedy_equivalence():
                                       jnp.int32(pos + i))
             ref.append(int(np.argmax(np.asarray(lg[0, 0]))))
         assert r.tokens == ref, f"request {r.rid} diverged"
+
+
+def test_served_path_spans_land_in_the_profilers_trace(tmp_path):
+    """Under the profiler every scheduler step carries its admit, price
+    and retire spans, each decode call its four phases between price and
+    retire, all in order and without overlap; tracing changes no token."""
+    import glob
+    import re
+    from unittest import mock
+
+    from jax.profiler import ProfileData
+
+    *_, sch, plain = _small_jax_serve()
+    sch.run(plain)
+    *_, ex, sch, reqs = _small_jax_serve()
+    with mock.patch.object(ex, "decode", wraps=ex.decode) as dec, \
+            jax.profiler.trace(str(tmp_path)):
+        rep = sch.run(reqs)
+    assert [r.tokens for r in reqs] == [r.tokens for r in plain]
+
+    pb, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                   for p in ProfileData.from_file(pb).planes
+                   if p.name.startswith("/host:")
+                   for line in p.lines for e in line.events
+                   if e.name.startswith("repro."))
+    assert all(b <= a2 for (_, b, _), (a2, _, _) in zip(spans, spans[1:]))
+    decode = ("repro.decode.inputs repro.decode.launch "
+              "repro.decode.sample repro.decode.readback ")
+    step = re.compile(f"repro.sched.admit repro.sched.price "
+                      f"(?:{decode})?repro.sched.retire ")
+    names = "".join(n + " " for _, _, n in spans)
+    assert len(step.findall(names)) == rep.steps
+    assert step.sub("", names) == ""
+    assert names.count(decode) == dec.call_count > 0
