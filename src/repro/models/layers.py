@@ -507,17 +507,24 @@ def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos, *,
     return o @ p["wo"], cache_k, cache_v
 
 
-def paged_attention_decode(p, cfg: ModelConfig, x, pool_k, pool_v,
+def paged_attention_decode(p, cfg: ModelConfig, x, pool_k, pool_v, layer,
                            block_tables, pos, *, window: int | None = None):
     """One-token decode against a paged KV pool (vLLM-style block table).
 
-    x: (B,1,D); pool_k/v: (n_blocks, block_size, Hkv, hd) — one shared
-    physical pool per layer; block_tables: (B, max_blocks) int32 mapping each
-    slot's logical block i to a physical block (0 = the reserved null block,
-    never owned by a live request, so idle slots write there harmlessly);
+    x: (B,1,D); pool_k/v: (n_layers, n_blocks, block_size, Hkv, hd) — a
+    run's stacked pools, one shared physical pool per layer; layer: int32
+    scalar, the layer this call reads and writes; block_tables: (B,
+    max_blocks) int32 mapping each slot's logical block i to a physical
+    block (0 = the reserved null block, never owned by a live request, so
+    idle slots write there harmlessly);
     pos: (B,) int32 per-slot token count — unlike the dense path the write
     pointer is per request, which is what lets continuous batching mix
     requests at different depths in one step.
+
+    The new rows are scattered at ``(layer, block, offset)`` and the keys
+    and values gathered at ``(layer, block_tables)`` straight from the
+    stacked pools, so no op holds a whole layer's pool: with the pools
+    donated, a step writes one row per layer in place.
 
     The gather `pool[table]` reconstructs each slot's cache in logical token
     order, so with max_blocks*block_size == s_max the score/softmax math is
@@ -538,16 +545,16 @@ def paged_attention_decode(p, cfg: ModelConfig, x, pool_k, pool_v,
     q = rope(q, pos[:, None], cfg.rope_theta)
     k = rope(k, pos[:, None], cfg.rope_theta)
 
-    BS = pool_k.shape[1]
+    BS = pool_k.shape[2]
     bidx = block_tables[jnp.arange(B), pos // BS]       # (B,) physical block
     off = pos % BS
-    pool_k = pool_k.at[bidx, off].set(k[:, 0].astype(pool_k.dtype))
-    pool_v = pool_v.at[bidx, off].set(v[:, 0].astype(pool_v.dtype))
+    pool_k = pool_k.at[layer, bidx, off].set(k[:, 0].astype(pool_k.dtype))
+    pool_v = pool_v.at[layer, bidx, off].set(v[:, 0].astype(pool_v.dtype))
 
     MB = block_tables.shape[1]
     S = MB * BS
-    gk = pool_k[block_tables].reshape(B, S, *pool_k.shape[2:])
-    gv = pool_v[block_tables].reshape(B, S, *pool_v.shape[2:])
+    gk = pool_k[layer, block_tables].reshape(B, S, *pool_k.shape[3:])
+    gv = pool_v[layer, block_tables].reshape(B, S, *pool_v.shape[3:])
     idx = jnp.arange(S)
     valid = idx[None, :] <= pos[:, None]
     if window is not None:

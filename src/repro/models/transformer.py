@@ -418,10 +418,10 @@ def scatter_prefill_cache(pools: list, cache: list, blocks, block_size: int,
     return out
 
 
-def _layer_decode_paged(p, cfg, kind, x, ent, block_tables, pos):
+def _layer_decode_paged(p, cfg, kind, x, ent, layer, block_tables, pos):
     window = cfg.window if kind == "local" else None
     y, pk, pv = L.paged_attention_decode(
-        p["attn"], cfg, L.rmsnorm(x, p["norm1"]), ent["k"], ent["v"],
+        p["attn"], cfg, L.rmsnorm(x, p["norm1"]), ent["k"], ent["v"], layer,
         block_tables, pos, window=window)
     x = x + y
     sub = L.moe_fwd if cfg.moe else L.mlp_fwd
@@ -433,16 +433,21 @@ def decode_step_paged(params, cfg: ModelConfig, pools: list, block_tables,
                       tokens, pos):
     """One-token serve step over paged pools.  tokens: (B,1) int32;
     block_tables: (B, max_blocks) int32; pos: (B,) int32 per-slot.
-    Returns (logits (B,1,V), new_pools)."""
+    Returns (logits (B,1,V), new_pools).
+
+    Each run's pools ride the scan's carry, so a layer writes its new rows
+    into them and reads its keys and values from them in place; jit the
+    step with ``pools`` donated to keep a second set of pools out of it."""
     x = params["embed"][tokens] * math.sqrt(cfg.d_model)
     new_pools = []
     for stacked, ent, (kind, n) in zip(params["runs"], pools, cfg.runs()):
-        def step(x, p_ent, kind=kind):
-            p, e = p_ent
-            x, e2 = _layer_decode_paged(p, cfg, kind, x, e, block_tables, pos)
-            return x, e2
+        def step(carry, p_layer, kind=kind):
+            x, e = carry
+            p, layer = p_layer
+            return _layer_decode_paged(p, cfg, kind, x, e, layer,
+                                       block_tables, pos), None
 
-        x, ent2 = lax.scan(step, x, (stacked, ent))
+        (x, ent2), _ = lax.scan(step, (x, ent), (stacked, jnp.arange(n)))
         new_pools.append(ent2)
     x = L.rmsnorm(x, params["final_norm"])
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

@@ -119,7 +119,9 @@ class JaxExecutor:
     per length) with ``full_local_cache=True`` and the dense result is
     scattered into the request's physical blocks; decode is one
     ``decode_step_paged`` over the whole slot table with per-slot positions
-    — idle slots write to the null block and are ignored."""
+    — idle slots write to the null block and are ignored.  Each decode
+    donates ``pools`` to the step, which writes the new rows in place: a
+    pool array read before a decode is deleted by it."""
 
     def __init__(self, cfg, mesh, *, n_blocks: int, block_size: int,
                  max_slots: int, max_blocks: int, seed: int = 0):
@@ -140,7 +142,8 @@ class JaxExecutor:
         self.tables = np.zeros((max_slots, max_blocks), np.int32)
         self._prefills: dict[int, object] = {}
         import functools
-        self._decode = jax.jit(functools.partial(T.decode_step_paged, cfg=cfg))
+        self._decode = jax.jit(functools.partial(T.decode_step_paged, cfg=cfg),
+                               donate_argnames=("pools",))
 
     def prefill_fn(self, S_p: int):
         """The jitted prefill program for padded length ``S_p``."""

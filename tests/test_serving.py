@@ -375,6 +375,21 @@ def _small_jax_serve():
     return cfg, ex, sch, reqs
 
 
+def _dense_greedy(cfg, params, prompt, n_new):
+    """The first ``n_new`` greedy tokens of a standalone dense prefill and
+    decode loop over ``prompt``."""
+    logits, cache, pos = T.prefill(params, cfg,
+                                   {"tokens": jnp.asarray(prompt)[None, :]},
+                                   s_max=len(prompt) + n_new)
+    ref = [int(np.argmax(np.asarray(logits[0, -1])))]
+    for i in range(n_new - 1):
+        lg, cache = T.decode_step(params, cfg, cache,
+                                  jnp.asarray([[ref[-1]]], jnp.int32),
+                                  jnp.int32(pos + i))
+        ref.append(int(np.argmax(np.asarray(lg[0, 0]))))
+    return ref
+
+
 def test_scheduler_jax_executor_greedy_equivalence():
     """End to end: the continuous-batching scheduler over the real paged
     executor must emit, per request, exactly the greedy tokens of a
@@ -388,16 +403,74 @@ def test_scheduler_jax_executor_greedy_equivalence():
 
     params = T.init_model(params_key, cfg)   # JaxExecutor used seed=0 too
     for r in reqs:
-        toks = jnp.asarray(r.prompt)[None, :]
-        logits, cache, pos = T.prefill(params, cfg, {"tokens": toks},
-                                       s_max=r.prompt_len + r.max_new_tokens)
-        ref = [int(np.argmax(np.asarray(logits[0, -1])))]
-        for i in range(r.max_new_tokens - 1):
-            lg, cache = T.decode_step(params, cfg, cache,
-                                      jnp.asarray([[ref[-1]]], jnp.int32),
-                                      jnp.int32(pos + i))
-            ref.append(int(np.argmax(np.asarray(lg[0, 0]))))
+        ref = _dense_greedy(cfg, params, r.prompt, r.max_new_tokens)
         assert r.tokens == ref, f"request {r.rid} diverged"
+
+
+def test_decode_step_touches_only_new_rows():
+    """The executor's decode step writes each slot's new K/V row into the
+    stacked pools and gathers from them in place: no value of the lowered
+    or the compiled step has a whole layer pool's shape (no slice of a
+    layer's pool, no write-back, no copy), and the donated pools alias
+    the step's outputs."""
+    _, ex, _, _ = _small_jax_serve()
+    layer = ex.pools[0]["k"].shape[1:]
+    assert ex.pools[0]["k"].dtype == jnp.bfloat16
+    n = ex.max_slots
+    lowered = ex._decode.lower(
+        params=ex.params, pools=ex.pools, block_tables=jnp.asarray(ex.tables),
+        tokens=jnp.zeros((n, 1), jnp.int32), pos=jnp.zeros((n,), jnp.int32))
+    compiled = lowered.compile()
+    for text, shape in [
+            (lowered.as_text(), "tensor<" + "x".join(map(str, layer)) + "xbf16>"),
+            (compiled.as_text(), "bf16[" + ",".join(map(str, layer)) + "]")]:
+        whole = [ln.strip() for ln in text.splitlines() if shape in ln]
+        assert not whole, whole[:3]
+    pool_bytes = sum(x.nbytes for x in jax.tree.leaves(ex.pools))
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+def test_jax_executor_decode_donates_pools():
+    """Every decode hands the executor's pools to the step, which deletes
+    the buffers it was given, and the pools it returns carry on: a prefill
+    into the second slot between decodes of the first, then decodes of
+    both, still give each request the dense greedy tokens."""
+    cfg, ex, _, _ = _small_jax_serve()
+    BS = ex.block_size
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, cfg.vocab, (L,)).astype(np.int32)
+               for L in (5, 3)]
+    free = iter(range(1, 1 + 2 * ex.max_blocks))
+    held, out = [], []
+
+    def prefill(slot):
+        L = len(prompts[slot])
+        held.append([next(free) for _ in range(blocks_needed(L, BS))])
+        out.append([ex.prefill(slot, held[slot], prompts[slot])])
+
+    def decode(slots):
+        pos = [len(prompts[s]) + len(out[s]) - 1 for s in slots]
+        for s, p in zip(slots, pos):
+            if p // BS >= len(held[s]):
+                held[s].append(next(free))
+                ex.extend(s, held[s][-1])
+        before = ex.pools[0]["k"]
+        nxt = ex.decode(slots, [out[s][-1] for s in slots], pos)
+        assert before.is_deleted()
+        assert not ex.pools[0]["k"].is_deleted()
+        for s, t in zip(slots, nxt):
+            out[s].append(t)
+
+    prefill(0)
+    for _ in range(3):
+        decode([0])
+    prefill(1)
+    for _ in range(5):
+        decode([0, 1])
+    assert [len(o) for o in out] == [9, 6]
+    for slot, (prompt, toks) in enumerate(zip(prompts, out)):
+        ref = _dense_greedy(cfg, ex.params, prompt, len(toks))
+        assert toks == ref, f"slot {slot} diverged"
 
 
 def test_served_path_spans_land_in_the_profilers_trace(tmp_path):
