@@ -360,5 +360,6 @@ def _minus_seed(c, seed, w):
 def _dtypes(c) -> dict:
     """The dtype each leaf is stored in, as a tree like the weights'."""
     tree = {n: s[1] for n, s in W.top_specs(c).items()}
-    tree["runs"] = [W.nest({p: s[1] for p, s in W.layer_specs(c).items()})]
+    tree["runs"] = [W.nest({p: s[1]
+                            for p, s in W.layer_specs(c, 0).items()})]
     return tree

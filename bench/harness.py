@@ -7,12 +7,18 @@ is ``bench/configs/<config>.json`` (read through :func:`as_run`), its
 traffic mix or training job is ``bench/traffic/<traffic>.json`` (whose
 ``kind`` picks the driver), its
 limits for ``correct`` are ``bench/limits/<cell>.json``, and each per-layer
-metric is read by ``bench/metrics/<metric>.py``.  Nothing here names a
-cell, a configuration or a metric.
+metric is read by ``bench/metrics/<metric>.py``.  The configuration file
+names two modules beside it: ``"arch"``, whose ``model_config``,
+``layer_specs``, ``residual_writers``, ``routing`` and ``layer_work`` are
+all that the harness, the weights and the work counts know of the
+architecture (the contract is in ``bench/configs/decoder_arch.py``), and
+``"reference"``, the plain reference.  Nothing here names a cell, a
+configuration, a metric or an architecture.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import math
@@ -89,9 +95,23 @@ def metric_reader(name: str) -> Callable:
 
 def reference_module(cfg_json: dict):
     """The plain reference named by a configuration file."""
-    path = os.path.join(BENCH, "configs", cfg_json["reference"] + ".py")
+    return _config_module(cfg_json["reference"])
+
+
+def arch_module(cfg_json: dict):
+    """The architecture module named by a configuration file."""
+    return _config_module(cfg_json["arch"])
+
+
+def _config_module(name: str):
+    return _load_module(os.path.join(BENCH, "configs", name + ".py"))
+
+
+@functools.lru_cache(maxsize=None)
+def _load_module(path: str):
+    name = os.path.splitext(os.path.basename(path))[0]
     spec = importlib.util.spec_from_file_location(
-        "bench_ref_" + cfg_json["reference"], path)
+        "bench_configs_" + name.replace("-", "_").replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -148,24 +168,7 @@ def peaks(device_kind: str) -> dict:
 
 def model_config(c: dict):
     """The program's ``ModelConfig`` for a configuration file."""
-    from repro.models.config import ModelConfig, MoECfg
-
-    L = int(c["num_hidden_layers"])
-    moe = None
-    if c.get("num_experts"):
-        moe = MoECfg(n_experts=int(c["num_experts"]),
-                     top_k=int(c["num_experts_per_tok"]),
-                     d_ff_expert=int(c["intermediate_size"]))
-    return ModelConfig(
-        name=c["name"], n_layers=L, d_model=int(c["hidden_size"]),
-        n_heads=int(c["num_attention_heads"]),
-        n_kv_heads=int(c["num_key_value_heads"]),
-        head_dim=int(c["head_dim"]), d_ff=int(c["intermediate_size"]),
-        vocab=int(c["vocab_size"]), pattern=("attn",) * L,
-        rope_theta=float(c["rope_theta"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]), moe=moe,
-        norm_eps=float(c["rms_norm_eps"]),
-        family="moe" if moe else "dense")
+    return arch_module(c).model_config(c)
 
 
 # ---------------------------------------------------------------------- #

@@ -68,8 +68,11 @@ def test_config_file_states_its_cut(c):
     assert f["name"] == c["name"] and f["source"] == c["source"]
     assert f["reduced"] == c["reduced"]
     assert set(f["published"]) == set(c["reduced"])
-    assert os.path.exists(os.path.join(harness.BENCH, "configs",
-                                       f["reference"] + ".py"))
+    for module in (f["reference"], f["arch"]):
+        assert os.path.exists(os.path.join(harness.BENCH, "configs",
+                                           module + ".py"))
+    cfg = harness.arch_module(f).model_config(harness.as_run(f))
+    assert len(cfg.pattern) == f["num_hidden_layers"]
 
 
 def test_a_new_cell_is_one_new_entry(tmp_path, monkeypatch):

@@ -2,16 +2,17 @@
 the same shape as the benchmark's, a few layers and narrow widths."""
 from bench import harness
 
-DENSE = dict(name="tiny-dense", reference="decoder_ref", hidden_size=64,
-             intermediate_size=128, num_hidden_layers=2,
+DENSE = dict(name="tiny-dense", reference="decoder_ref", arch="decoder_arch",
+             hidden_size=64, intermediate_size=128, num_hidden_layers=2,
              num_attention_heads=4, num_key_value_heads=2, head_dim=16,
              vocab_size=512, tie_word_embeddings=True, rope_theta=10000.0,
              rms_norm_eps=1e-6)
-MOE = dict(name="tiny-moe", reference="decoder_ref", hidden_size=64,
-           intermediate_size=32, num_hidden_layers=1, num_attention_heads=4,
-           num_key_value_heads=4, head_dim=16, vocab_size=256,
-           tie_word_embeddings=False, num_experts=8, num_experts_per_tok=2,
-           norm_topk_prob=True, rope_theta=10000.0, rms_norm_eps=1e-6)
+MOE = dict(name="tiny-moe", reference="decoder_ref", arch="decoder_arch",
+           hidden_size=64, intermediate_size=32, num_hidden_layers=1,
+           num_attention_heads=4, num_key_value_heads=4, head_dim=16,
+           vocab_size=256, tie_word_embeddings=False, num_experts=8,
+           num_experts_per_tok=2, norm_topk_prob=True, rope_theta=10000.0,
+           rms_norm_eps=1e-6)
 CHAT = dict(kind="serve", mesh=[1, 1, 1], clients=1, block_size=16,
             prefill_token_budget=256, kv_pool_tokens=1024,
             prompt_lens=[16, 32, 64], prompt_weights=[0.3, 0.4, 0.3],

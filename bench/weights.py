@@ -1,11 +1,16 @@
 """Weights from ``--seed``, made on the device in one jitted call.
 
-The tree has the layout the program's decoder takes (``embed``,
-``final_norm``, optional ``lm_head``, and one run of stacked attention
-layers), built here from the configuration file alone, so the plain
-reference can make any one layer of the same weights again without the
-program.  Every leaf of layer ``l`` comes from its own key, so the stacked
-tree and the layer-by-layer reference hold the same numbers.
+The tree has the layout the program's decoder takes: ``embed``,
+``final_norm``, an optional ``lm_head``, and ``runs``, one stacked run per
+maximal run of block kinds of ``model_config(c).runs()``.  What each layer
+holds comes from the configuration's arch module (``"arch"`` in its file,
+see ``bench/configs/decoder_arch.py``): ``layer_specs`` gives its leaves,
+``residual_writers`` the leaves that write the residual stream, and
+``routing`` the router, if any.  Nothing here names an architecture, so
+the plain reference can make any one layer of the same weights again from
+the configuration file alone, without the program.  Every leaf of layer
+``l`` comes from its own key, so the stacked tree and the layer-by-layer
+reference hold the same numbers.
 """
 from __future__ import annotations
 
@@ -13,6 +18,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from bench import harness
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 NORM_SCALE = 0.25   # norm weights w, applied as (1 + w)
@@ -25,14 +32,15 @@ NORM_SCALE = 0.25   # norm weights w, applied as (1 + w)
 GAIN = 3.0
 # Top-k routing.  With every matrix random, a token's k-th and (k+1)-th
 # router logits lie so close that bfloat16 rounding flips some tokens'
-# choice, and a check of the gradient then measures flips, not precision.  So each vocabulary id's k experts
-# are drawn from the seed and written into the first E dims of its
-# embedding row (ROUTE_VALUE after the sqrt(D) scale), no layer writes
-# those dims (their columns of attention's ``wo`` and the experts' ``w_out``
-# are zero), and the router reads dim e for expert e at ROUTE_GAIN: the
-# chosen k lead the rest by several logits, which no rounding crosses.  The
-# gates among the k stay random and smooth.  The work is unchanged: the
-# same shapes, and every expert gets k/E of the tokens in expectation.
+# choice, and a check of the gradient then measures flips, not precision.
+# So each vocabulary id's k experts are drawn from the seed and written
+# into the first E dims of its embedding row (ROUTE_VALUE after the sqrt(D)
+# scale), no layer writes those dims (their columns of every residual
+# writer the arch module names are zero), and the router reads dim e for
+# expert e at ROUTE_GAIN: the chosen k lead the rest by several logits,
+# which no rounding crosses.  The gates among the k stay random and
+# smooth.  The work is unchanged: the same shapes, and every expert gets
+# k/E of the tokens in expectation.
 ROUTE_VALUE = 3.0
 ROUTE_GAIN = 6.0
 
@@ -48,28 +56,17 @@ def top_specs(c: dict) -> dict:
     return out
 
 
-def layer_specs(c: dict) -> dict:
-    """path -> (shape of one layer, dtype, scale)."""
-    D, hd = c["hidden_size"], c["head_dim"]
-    Q, KV = c["num_attention_heads"] * hd, c["num_key_value_heads"] * hd
-    F = c["intermediate_size"]
-    s = {("norm1",): ((D,), F32, NORM_SCALE),
-         ("norm2",): ((D,), F32, NORM_SCALE),
-         ("attn", "wq"): ((D, Q), BF16, GAIN * D ** -0.5),
-         ("attn", "wk"): ((D, KV), BF16, GAIN * D ** -0.5),
-         ("attn", "wv"): ((D, KV), BF16, GAIN * D ** -0.5),
-         ("attn", "wo"): ((Q, D), BF16, GAIN * Q ** -0.5)}
-    if c.get("num_experts"):
-        E = c["num_experts"]
-        s |= {("mlp", "router"): ((D, E), F32, D ** -0.5),
-              ("mlp", "w_in"): ((E, D, F), BF16, GAIN * D ** -0.5),
-              ("mlp", "w_gate"): ((E, D, F), BF16, GAIN * D ** -0.5),
-              ("mlp", "w_out"): ((E, F, D), BF16, GAIN * F ** -0.5)}
-    else:
-        s |= {("mlp", "wi"): ((D, F), BF16, GAIN * D ** -0.5),
-              ("mlp", "wg"): ((D, F), BF16, GAIN * D ** -0.5),
-              ("mlp", "wo"): ((F, D), BF16, GAIN * F ** -0.5)}
-    return s
+def layer_specs(c: dict, layer: int) -> dict:
+    """path -> (shape of one layer, dtype, scale) of layer ``layer``."""
+    return harness.arch_module(c).layer_specs(c, layer)
+
+
+def _layout(c: dict, layer: int) -> tuple:
+    """Layer ``layer``'s leaf specs and residual writers, hashable: layers
+    of one layout share their programs."""
+    arch = harness.arch_module(c)
+    return (tuple(arch.layer_specs(c, layer).items()),
+            tuple(arch.residual_writers(c, layer)))
 
 
 def _draw(key, shape, scale, normal=False):
@@ -82,32 +79,37 @@ def _top_leaf(c, key, name, spec):
     shape, dtype, scale = spec
     x = _draw(jax.random.fold_in(key, hash_name(name)), shape, scale,
               normal=name == "embed")
-    if name == "embed" and c.get("num_experts"):
-        x = _route_rows(c, key, x)
+    route = harness.arch_module(c).routing(c)
+    if name == "embed" and route is not None:
+        x = _route_rows(key, x, *route[1:])
     return x.astype(dtype)
 
 
-def _layer_leaf(c, key, path, spec, layer):
+def _layer_leaf(c, key, path, spec, layer, writers):
     shape, dtype, scale = spec
     k = jax.random.fold_in(jax.random.fold_in(key, hash_name("/".join(path))),
                            layer)
     x = _draw(k, shape, scale)
-    if c.get("num_experts"):
-        E = c["num_experts"]
-        if path in (("attn", "wo"), ("mlp", "w_out")):
+    route = harness.arch_module(c).routing(c)
+    if route is not None:
+        router, E, _ = route
+        if path in writers:
             x = x.at[..., :E].set(0.0)
-        elif path == ("mlp", "router"):
+        elif path == router:
+            if shape[-1] != E:
+                raise ValueError(f"router {path} is {shape[-1]} wide, "
+                                 f"routing() says {E}")
             x = x.at[:E].set(ROUTE_GAIN * jnp.eye(E, dtype=F32))
     return x.astype(dtype)
 
 
-def _route_rows(c, key, emb):
+def _route_rows(key, emb, E, k):
     """The embedding with each row's first E dims marking its k experts."""
-    V, E, k = c["vocab_size"], c["num_experts"], c["num_experts_per_tok"]
+    V, D = emb.shape
     u = jax.random.uniform(jax.random.fold_in(key, hash_name("route")),
                            (V, E))
     rank = jnp.argsort(jnp.argsort(-u, axis=1), axis=1)
-    mark = jnp.where(rank < k, ROUTE_VALUE / c["hidden_size"] ** 0.5, 0.0)
+    mark = jnp.where(rank < k, ROUTE_VALUE / D ** 0.5, 0.0)
     return emb.at[:, :E].set(mark)
 
 
@@ -155,27 +157,39 @@ class Frozen(dict):
 
 
 def _params_fn(c: dict, out_shardings):
-    L = c["num_hidden_layers"]
+    runs, first = [], 0
+    for _, n in harness.model_config(c).runs():
+        layout = _layout(c, first)
+        if any(_layout(c, l) != layout for l in range(first, first + n)):
+            raise ValueError(f"layers {first}..{first + n - 1} form one run "
+                             "of the program but differ in their leaves")
+        runs.append((first, n, layout))
+        first += n
 
     def build(words):
         key = _key_from_words(words)
         tree = {n: _top_leaf(c, key, n, s) for n, s in top_specs(c).items()}
-        layers = jnp.arange(L)
-        run = {path: jax.vmap(lambda l, p=path, s=spec:
-                              _layer_leaf(c, key, p, s, l))(layers)
-               for path, spec in layer_specs(c).items()}
-        tree["runs"] = [nest(run)]
+        tree["runs"] = []
+        for lo, n, (specs, writers) in runs:
+            layers = jnp.arange(lo, lo + n)
+            run = {path: jax.vmap(lambda l, p=path, s=spec:
+                                  _layer_leaf(c, key, p, s, l, writers))(
+                                      layers)
+                   for path, spec in specs}
+            tree["runs"].append(nest(run))
         return tree
 
     return jax.jit(build, out_shardings=out_shardings)
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_fn(c: Frozen):
+def _layer_fn(c: Frozen, layout: tuple):
+    specs, writers = layout
+
     def build(words, layer):
         key = _key_from_words(words)
-        return nest({p: _layer_leaf(c, key, p, s, layer)
-                      for p, s in layer_specs(c).items()})
+        return nest({p: _layer_leaf(c, key, p, s, layer, writers)
+                      for p, s in specs})
     return jax.jit(build)
 
 
@@ -189,7 +203,8 @@ def _top_fn(c: Frozen):
 
 def make_layer(c: dict, seed: int, layer: int) -> dict:
     """Layer ``layer``'s leaves, as the stacked tree holds them."""
-    return _layer_fn(Frozen(c))(_seed_words(seed), jnp.int32(layer))
+    return _layer_fn(Frozen(c), _layout(c, layer))(_seed_words(seed),
+                                                   jnp.int32(layer))
 
 
 def make_top(c: dict, seed: int) -> dict:
