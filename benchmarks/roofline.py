@@ -40,7 +40,7 @@ def _mlp_flops_per_tok(cfg):
     if cfg.moe is not None:
         m = cfg.moe
         routed = 2 * 3 * cfg.d_model * m.d_ff_expert * m.top_k
-        shared = 2 * 3 * cfg.d_model * cfg.d_ff if m.shared_expert else 0
+        shared = 2 * 3 * cfg.d_model * cfg.d_ff_shared if m.shared_expert else 0
         router = 2 * cfg.d_model * m.n_experts
         return routed + shared + router
     mult = 3 if cfg.activation in ("swiglu", "geglu") else 2

@@ -22,6 +22,7 @@ ARCHS = [
     "seamless_m4t_medium",
     "rwkv6_1p6b",
     "gpt_100m",  # e2e training example model (paper-scale driver)
+    "deepseek_v2_lite_16b",
 ]
 
 _ALIAS = {a.replace("_", "-"): a for a in ARCHS}
@@ -37,6 +38,7 @@ _ALIAS.update({
     "seamless-m4t-medium": "seamless_m4t_medium",
     "rwkv6-1.6b": "rwkv6_1p6b",
     "gpt-100m": "gpt_100m",
+    "deepseek-v2-lite-16b": "deepseek_v2_lite_16b",
 })
 
 

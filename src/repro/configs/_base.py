@@ -3,9 +3,11 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.models.config import ModelConfig, MoECfg, EncDecCfg, pattern_repeat
+from repro.models.config import (EncDecCfg, MLACfg, ModelConfig, MoECfg,
+                                 YarnCfg, pattern_repeat)
 
-__all__ = ["ModelConfig", "MoECfg", "EncDecCfg", "pattern_repeat", "shrink"]
+__all__ = ["ModelConfig", "MoECfg", "MLACfg", "YarnCfg", "EncDecCfg",
+           "pattern_repeat", "shrink"]
 
 
 def shrink(cfg: ModelConfig, n_layers: int = 4) -> ModelConfig:

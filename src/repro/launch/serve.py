@@ -118,13 +118,14 @@ def serve(arch: str, n_requests: int, prompt_len: int, gen_len: int,
     from repro.core.engine import Engine
     max_slots = min(n_requests, 8)
     n_blocks = 1 + max_slots * (s_max // block_size)
-    ex = JaxExecutor(cfg, mesh, n_blocks=n_blocks, block_size=block_size,
-                     max_slots=max_slots, max_blocks=s_max // block_size)
     act_itemsize = jnp.dtype(cfg.dtype).itemsize
-    # one registry spans engine + scheduler + monitor when scraping: the
-    # exposition file must read as ONE process, not three
+    # one registry spans executor + engine + scheduler + monitor when
+    # scraping: the exposition file must read as ONE process, not four
     from repro.obs import MetricsRegistry
     registry = MetricsRegistry() if metrics_out or monitor else None
+    ex = JaxExecutor(cfg, mesh, n_blocks=n_blocks, block_size=block_size,
+                     max_slots=max_slots, max_blocks=s_max // block_size,
+                     metrics=registry)
     eng = Engine(wcomm, policy="fifo" if policy == "fifo" else "priority",
                  age_rate=wbytes, metrics=registry)
     mon = None

@@ -251,10 +251,13 @@ def make_decode_step(cfg: ModelConfig, mesh):
 def paged_pool_shardings(cfg: ModelConfig, mesh, pools_abstract) -> Any:
     """Paged pools have no batch dim — any request's blocks live anywhere in
     the shared pool — so the only safe static partition is over the KV-head
-    dim (model axis), mirroring tensor-parallel attention."""
+    dim (model axis), mirroring tensor-parallel attention.  Latent (mla)
+    pools have no head axis and are replicated."""
     msz = mesh.shape.get("model", 1)
 
     def spec_for(leaf):
+        if leaf.ndim != 5:
+            return NamedSharding(mesh, P())
         h_ax = _maybe("model", leaf.shape[3], msz)
         return NamedSharding(mesh, P(None, None, None, h_ax, None))
 

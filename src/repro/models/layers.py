@@ -18,6 +18,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .config import ModelConfig
@@ -50,6 +51,52 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def rope_inv_freq(dim: int, theta: float, yarn=None) -> np.ndarray:
+    """The ``dim // 2`` rotation frequencies of a rotary embedding; with
+    ``yarn`` (a :class:`~repro.models.config.YarnCfg`) YaRN's blend of
+    interpolated and extrapolated frequencies, as in DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding`` and ``transformers``'
+    ``_compute_yarn_parameters``."""
+    pos_freqs = theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    extra = 1.0 / pos_freqs
+    if yarn is None:
+        return extra.astype(np.float32)
+    inter = 1.0 / (yarn.factor * pos_freqs)
+
+    def correction_dim(rotations):
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    extra_share = 1.0 - ramp
+    return (inter * (1.0 - extra_share) + extra * extra_share).astype(
+        np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor: 0.1 m ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_pairs(x: jax.Array, positions: jax.Array,
+               inv_freq: np.ndarray) -> jax.Array:
+    """Rotary embedding of interleaved pairs (x[2i], x[2i+1]) by angle
+    ``position * inv_freq[i]``, as DeepSeek-V2 rotates (``view_as_complex``
+    on (..., d/2, 2)).  x: (..., S, H, d); positions: (..., S)."""
+    ang = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq)
+    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    xr = x.reshape(*x.shape[:-1], -1, 2)
+    x1, x2 = xr[..., 0], xr[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
 
 
 def _uniform(key, shape, scale, dtype=jnp.float32):
@@ -626,12 +673,192 @@ def cross_attention_decode(p, cfg: ModelConfig, x, ck, cv):
 
 
 # ---------------------------------------------------------------------- #
+# Multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------- #
+#
+# Per token, x W_kv_a gives a latent c~ (kv_lora_rank) and one rope key
+# k_pe (qk_rope_dim) that every head shares; c = RMSNorm(c~).  Each head's
+# key is [c W_UK_h, k_pe] and its value c W_UV_h, where W_kv_b = [W_UK_h,
+# W_UV_h]_h.  The cache holds only c and k_pe (576 values a token a layer
+# at DeepSeek-V2's widths): no head axis.  Prefill decompresses k and v and
+# runs flash attention at the query-key width; decode never does: W_UK is
+# folded into the query and W_UV is applied to the attention-weighted
+# latent ("absorbed").
+#
+# The paged pools keep c as (layer, block, token, kv_lora_rank) and k_pe
+# as (layer, block, token * qk_rope_dim), a block's rope keys side by side
+# in one row.  A (…, 16, 576) pool of whole rows is not kept: its minor
+# dim is no multiple of the TPU's 128 lanes, so the TPU's default layout
+# transposes it (block ids minor-most) and every decode step would copy
+# the whole pool into the layout its gather needs and back.
+
+def init_mla(key, cfg: ModelConfig) -> dict:
+    ks = jax.random.split(key, 4)
+    D, H, a = cfg.d_model, cfg.n_heads, cfg.mla
+    return {
+        "wq": dense_init(ks[0], D, H * a.qk_dim),
+        "wkv_a": dense_init(ks[1], D, a.latent_dim),
+        "kv_norm": jnp.zeros((a.kv_lora_rank,), jnp.float32),
+        "wkv_b": dense_init(ks[2], a.kv_lora_rank,
+                            H * (a.qk_nope_dim + a.v_head_dim)),
+        "wo": dense_init(ks[3], H * a.v_head_dim, D),
+    }
+
+
+def mla_rope_freq(cfg: ModelConfig) -> np.ndarray:
+    return rope_inv_freq(cfg.mla.qk_rope_dim, cfg.rope_theta, cfg.yarn)
+
+
+def mla_mscale_sq(cfg: ModelConfig) -> float:
+    """The factor on the softmax scale qk_dim^-0.5: YaRN's
+    mscale(factor, mscale_all_dim) squared, as DeepSeek-V2's source and vLLM
+    apply it (``transformers``' port leaves it out).  The rotation itself
+    is scaled by mscale(factor, mscale) / mscale(factor, mscale_all_dim),
+    1 for DeepSeek-V2, so it is not applied."""
+    y = cfg.yarn
+    if y is None or not y.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    return cfg.mla.qk_dim ** -0.5 * mla_mscale_sq(cfg)
+
+
+def mla_latent(p, cfg: ModelConfig, x, positions):
+    """(q_nope, q_pe, c, k_pe) of tokens x: (B,S,D) at positions (S,) or
+    (B,S).  q_nope (B,S,H,qk_nope_dim) and the rotated q_pe
+    (B,S,H,qk_rope_dim) in float32; the normalised latent c
+    (B,S,kv_lora_rank) and the rotated rope key k_pe (B,S,qk_rope_dim) in
+    x's dtype: what the cache holds."""
+    B, S, _ = x.shape
+    a, H = cfg.mla, cfg.n_heads
+    freq = mla_rope_freq(cfg)
+    q = jnp.matmul(x, p["wq"], preferred_element_type=jnp.float32)
+    q = q.reshape(B, S, H, a.qk_dim)
+    q_pe = rope_pairs(q[..., a.qk_nope_dim:], positions, freq)
+    kv_a = x @ p["wkv_a"]
+    c = rmsnorm(kv_a[..., :a.kv_lora_rank], p["kv_norm"])
+    k_pe = rope_pairs(kv_a[..., None, a.kv_lora_rank:], positions, freq)
+    return q[..., :a.qk_nope_dim], q_pe, c, k_pe[..., 0, :]
+
+
+def mla_prefill(p, cfg: ModelConfig, x):
+    """Causal MLA over x: (B,S,D), decompressed.  Returns (y, c, k_pe),
+    the cache's latents (B,S,kv_lora_rank) and rope keys (B,S,qk_rope_dim)
+    in ``cfg.dtype``.
+
+    Keys are qk_dim wide and values v_head_dim; the flash kernel takes one
+    head width, so v is padded with zeros to qk_dim (as ``transformers``
+    does for FlashAttention-2) and the pad is cut off its output.  The
+    kernel scales scores by qk_dim^-0.5; YaRN's mscale^2 rides on q."""
+    B, S, _ = x.shape
+    a, H = cfg.mla, cfg.n_heads
+    q_nope, q_pe, c, k_pe = mla_latent(p, cfg, x, jnp.arange(S))
+    kv = (c @ p["wkv_b"]).reshape(B, S, H, a.qk_nope_dim + a.v_head_dim)
+    k = jnp.concatenate([kv[..., :a.qk_nope_dim], jnp.broadcast_to(
+        k_pe[:, :, None], (B, S, H, a.qk_rope_dim))], axis=-1)
+    q = (jnp.concatenate([q_nope, q_pe], axis=-1)
+         * mla_mscale_sq(cfg)).astype(x.dtype)
+    v = jnp.pad(kv[..., a.qk_nope_dim:],
+                [(0, 0)] * 3 + [(0, a.qk_dim - a.v_head_dim)])
+    o = chunked_attention(q, k, v, causal=True)[..., :a.v_head_dim]
+    y = o.reshape(B, S, H * a.v_head_dim) @ p["wo"]
+    dt = jnp.dtype(cfg.dtype)
+    return y, c.astype(dt), k_pe.astype(dt)
+
+
+def mla_fwd(p, cfg: ModelConfig, x) -> jax.Array:
+    return mla_prefill(p, cfg, x)[0]
+
+
+def mla_absorbed(p, cfg: ModelConfig, q_nope, q_pe, c, pe, valid):
+    """One query per slot against its cached tokens, absorbed.
+
+    q_nope (B,H,qk_nope_dim), q_pe (B,H,qk_rope_dim) float32; the cache in
+    M blocks of T tokens: latents c (B,M,T,kv_lora_rank), rope keys pe
+    (B,M,T*qk_rope_dim) (a block's keys side by side), valid (B,M,T).
+    score_{h,t} = (q_nope_h W_UK_h^T) . c_t + q_pe_h . k_pe_t;
+    o_h = (sum_t p_{h,t} c_t) W_UV_h.  Returns (B,H,v_head_dim) float32.
+    Nothing holds a per-head key or value of the context."""
+    a, H = cfg.mla, cfg.n_heads
+    B, M, T, r = c.shape
+    wkv_b = p["wkv_b"].astype(jnp.float32).reshape(
+        r, H, a.qk_nope_dim + a.v_head_dim)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, wkv_b[..., :a.qk_nope_dim])
+    ctx = c.astype(jnp.float32)
+    s = jnp.einsum("bhr,bmtr->bhmt", q_lat, ctx)
+    # a block's rope keys lie side by side in one row: score that row
+    # against the query laid out block-diagonally, (T*dr, T)
+    q_blk = jnp.einsum("bhd,tu->bhtdu", q_pe, jnp.eye(T, dtype=jnp.float32))
+    s = s + jnp.einsum("bmk,bhku->bhmu", pe.astype(jnp.float32),
+                       q_blk.reshape(B, H, T * a.qk_rope_dim, T))
+    s = jnp.where(valid[:, None], s * mla_softmax_scale(cfg), -1e30)
+    pr = jax.nn.softmax(s, axis=(-2, -1))
+    o_lat = jnp.einsum("bhmt,bmtr->bhr", pr, ctx)
+    return jnp.einsum("bhr,rhv->bhv", o_lat, wkv_b[..., a.qk_nope_dim:])
+
+
+def _mla_out(p, cfg: ModelConfig, o, x):
+    B = x.shape[0]
+    return (o.reshape(B, 1, cfg.n_heads * cfg.mla.v_head_dim).astype(x.dtype)
+            @ p["wo"])
+
+
+def mla_decode(p, cfg: ModelConfig, x, cache_c, cache_pe, pos):
+    """One-token MLA decode against a dense cache: latents (B,S_c,
+    kv_lora_rank) and rope keys (B,S_c,qk_rope_dim); pos: scalar int32,
+    the tokens already cached."""
+    B = x.shape[0]
+    q_nope, q_pe, c, k_pe = mla_latent(p, cfg, x, pos[None])
+    S_c = cache_c.shape[1]
+    slot = jnp.minimum(pos, S_c - 1)
+    cache_c = lax.dynamic_update_slice(cache_c, c.astype(cache_c.dtype),
+                                       (0, slot, 0))
+    cache_pe = lax.dynamic_update_slice(cache_pe, k_pe.astype(cache_pe.dtype),
+                                        (0, slot, 0))
+    valid = jnp.broadcast_to((jnp.arange(S_c) <= pos)[None, :, None],
+                             (B, S_c, 1))
+    o = mla_absorbed(p, cfg, q_nope[:, 0], q_pe[:, 0], cache_c[:, :, None],
+                     cache_pe, valid)
+    return _mla_out(p, cfg, o, x), cache_c, cache_pe
+
+
+def paged_mla_decode(p, cfg: ModelConfig, x, pool_c, pool_pe, layer,
+                     block_tables, pos):
+    """One-token MLA decode against a run's paged pools: latents (n_layers,
+    n_blocks, block_size, kv_lora_rank) and rope keys (n_layers, n_blocks,
+    block_size * qk_rope_dim).  Writes each slot's new token at ``(layer,
+    block, offset)`` and reads the blocks of its table, as
+    :func:`paged_attention_decode` does for k and v."""
+    B = x.shape[0]
+    dr = cfg.mla.qk_rope_dim
+    q_nope, q_pe, c, k_pe = mla_latent(p, cfg, x, pos[:, None])
+    BS = pool_c.shape[2]
+    bidx = block_tables[jnp.arange(B), pos // BS]
+    off = pos % BS
+    pool_c = pool_c.at[layer, bidx, off].set(c[:, 0].astype(pool_c.dtype))
+    lanes = (off * dr)[:, None] + jnp.arange(dr)
+    pool_pe = pool_pe.at[layer, bidx[:, None], lanes].set(
+        k_pe[:, 0].astype(pool_pe.dtype))
+    MB = block_tables.shape[1]
+    idx = jnp.arange(MB)[:, None] * BS + jnp.arange(BS)
+    valid = idx[None] <= pos[:, None, None]
+    # gathered once: the scores and the weighted sum both read the latents
+    c_rows, pe_rows = lax.optimization_barrier(
+        (pool_c[layer, block_tables], pool_pe[layer, block_tables]))
+    o = mla_absorbed(p, cfg, q_nope[:, 0], q_pe[:, 0], c_rows, pe_rows,
+                     valid)
+    return _mla_out(p, cfg, o, x), pool_c, pool_pe
+
+
+# ---------------------------------------------------------------------- #
 # MLP / MoE
 # ---------------------------------------------------------------------- #
 
-def init_mlp(key, cfg: ModelConfig) -> dict:
+def init_mlp(key, cfg: ModelConfig, d_ff: int | None = None) -> dict:
     ks = jax.random.split(key, 3)
-    D, F = cfg.d_model, cfg.d_ff
+    D, F = cfg.d_model, d_ff or cfg.d_ff
     p = {"wi": dense_init(ks[0], D, F), "wo": dense_init(ks[1], F, D)}
     if cfg.activation in ("swiglu", "geglu"):
         p["wg"] = dense_init(ks[2], D, F)
@@ -654,36 +881,58 @@ def init_moe(key, cfg: ModelConfig) -> dict:
     ks = jax.random.split(key, 5)
     D, Fe, E = cfg.d_model, m.d_ff_expert, m.n_experts
     scale = 1.0 / math.sqrt(D)
+    Eh = m.held
     p = {
         "router": dense_init(ks[0], D, E).astype(jnp.float32),
-        "w_in": _uniform(ks[1], (E, D, Fe), scale).astype(jnp.bfloat16),
-        "w_gate": _uniform(ks[2], (E, D, Fe), scale).astype(jnp.bfloat16),
-        "w_out": _uniform(ks[3], (E, Fe, D), 1.0 / math.sqrt(Fe)).astype(jnp.bfloat16),
+        "w_in": _uniform(ks[1], (Eh, D, Fe), scale).astype(jnp.bfloat16),
+        "w_gate": _uniform(ks[2], (Eh, D, Fe), scale).astype(jnp.bfloat16),
+        "w_out": _uniform(ks[3], (Eh, Fe, D), 1.0 / math.sqrt(Fe)).astype(jnp.bfloat16),
     }
     if m.shared_expert:
-        p["shared"] = init_mlp(ks[4], cfg)
+        p["shared"] = init_mlp(ks[4], cfg, cfg.d_ff_shared)
     return p
 
 
 MOE_CHUNK = 8192  # token-block size for the scanned dispatch
 
 
-def _moe_block(p, m, xt):
-    """Route + dispatch + expert compute for one block of tokens (T, D)."""
-    T, D = xt.shape
+def _route(p, m, xt):
+    """(gates, expert ids), each (T, top_k): greedy top-k of the softmax
+    over all ``n_experts``, renormalised or not."""
     logits = xt.astype(jnp.float32) @ p["router"]
-    gates, eidx = lax.top_k(jax.nn.softmax(logits, axis=-1), m.top_k)  # (T,k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    gates, eidx = lax.top_k(jax.nn.softmax(logits, axis=-1), m.top_k)
+    if m.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates, eidx
+
+
+def _moe_block(p, m, xt):
+    """Route + dispatch + expert compute for one block of tokens (T, D).
+
+    Dropless: the dispatch buffer has a row for every (token, choice).
+    Where this device holds a share of the experts, ``[first_held,
+    first_held + held)``, choices of other experts sort after the held
+    groups, which ``ragged_dot`` leaves out, and are masked off."""
+    T, D = xt.shape
+    gates, eidx = _route(p, m, xt)                 # (T,k)
     flat_e = eidx.reshape(-1)                      # (T*k,)
+    share = m.held != m.n_experts
+    if share:
+        local = flat_e - m.first_held
+        mine = (local >= 0) & (local < m.held)
+        flat_e = jnp.where(mine, local, m.held)    # other experts sort last
     order = jnp.argsort(flat_e)                    # stable sort by expert
     tok_for = order // m.top_k                     # token index per slot
     xs = xt[tok_for]                               # (T*k, D) sorted by expert
-    group_sizes = jnp.bincount(flat_e, length=m.n_experts)
+    group_sizes = jnp.bincount(flat_e, length=m.held)
     h = lax.ragged_dot(xs, p["w_in"], group_sizes)
     g = lax.ragged_dot(xs, p["w_gate"], group_sizes)
     h = jax.nn.silu(g) * h
     yo = lax.ragged_dot(h, p["w_out"], group_sizes)  # (T*k, D)
-    yo = yo[jnp.argsort(order)].reshape(T, m.top_k, D)
+    yo = yo[jnp.argsort(order)]
+    if share:
+        yo = jnp.where(mine[:, None], yo, 0)
+    yo = yo.reshape(T, m.top_k, D)
     return jnp.einsum("tk,tkd->td", gates.astype(yo.dtype), yo)
 
 
@@ -703,9 +952,7 @@ def _moe_block_ep(p, m, xt, axis: str):
     E_local = p["w_in"].shape[0]          # local expert slice
     e0 = rank * E_local
 
-    logits = xt.astype(jnp.float32) @ p["router"]   # router is replicated
-    gates, eidx = lax.top_k(jax.nn.softmax(logits, axis=-1), m.top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    gates, eidx = _route(p, m, xt)                  # router is replicated
 
     flat_e = eidx.reshape(-1)                       # (T*k,) global expert ids
     local = flat_e - e0
@@ -753,7 +1000,7 @@ def moe_fwd(p, cfg: ModelConfig, x, chunk: int = MOE_CHUNK) -> jax.Array:
     am = jsh.get_abstract_mesh()
     if "model" in am.axis_names:
         msize = am.shape["model"]
-        if msize > 1 and m.n_experts % msize == 0:
+        if msize > 1 and m.n_experts % msize == 0 and m.held == m.n_experts:
             # dp axes still in AUTO state (e.g. the GSPMD serving path) must
             # become manual alongside `model`, with tokens sharded over them
             # — otherwise the P() token spec would force an all-gather of

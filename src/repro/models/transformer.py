@@ -24,13 +24,16 @@ from .config import ModelConfig
 # Init
 # ---------------------------------------------------------------------- #
 
-def _init_layer(key, cfg: ModelConfig, kind: str, cross: bool, causal: bool):
+def _init_layer(key, cfg: ModelConfig, kind: str, cross: bool, causal: bool,
+                moe: bool):
     ks = jax.random.split(key, 4)
     D = cfg.d_model
     p: dict[str, Any] = {"norm1": jnp.zeros((D,), jnp.float32),
                          "norm2": jnp.zeros((D,), jnp.float32)}
     if kind in ("attn", "local"):
         p["attn"] = L.init_attention(ks[0], cfg)
+    elif kind == "mla":
+        p["attn"] = L.init_mla(ks[0], cfg)
     elif kind == "rglru":
         p["rec"] = L.init_rglru(ks[0], cfg)
     elif kind == "rwkv6":
@@ -38,16 +41,18 @@ def _init_layer(key, cfg: ModelConfig, kind: str, cross: bool, causal: bool):
     else:
         raise ValueError(kind)
     if kind != "rwkv6":
-        p["mlp"] = L.init_moe(ks[1], cfg) if cfg.moe else L.init_mlp(ks[1], cfg)
+        p["mlp"] = L.init_moe(ks[1], cfg) if moe else L.init_mlp(ks[1], cfg)
     if cross:
         p["norm_x"] = jnp.zeros((D,), jnp.float32)
         p["xattn"] = L.init_attention(ks[2], cfg, cross=True)
     return p
 
 
-def _init_run(key, cfg: ModelConfig, kind: str, n: int, cross: bool, causal: bool):
+def _init_run(key, cfg: ModelConfig, kind: str, n: int, cross: bool,
+              causal: bool, moe: bool):
     keys = jax.random.split(key, n)
-    return jax.vmap(lambda k: _init_layer(k, cfg, kind, cross, causal))(keys)
+    return jax.vmap(lambda k: _init_layer(k, cfg, kind, cross, causal,
+                                          moe))(keys)
 
 
 def init_model(key, cfg: ModelConfig) -> dict:
@@ -59,15 +64,18 @@ def init_model(key, cfg: ModelConfig) -> dict:
     }
     cross = cfg.enc_dec is not None
     params["runs"] = [
-        _init_run(jax.random.fold_in(ks[1], i), cfg, kind, n, cross, True)
-        for i, (kind, n) in enumerate(cfg.runs())
+        _init_run(jax.random.fold_in(ks[1], i), cfg, kind, n, cross, True,
+                  cfg.layer_moe(first))
+        for i, ((kind, n), first) in enumerate(zip(cfg.runs(),
+                                                   cfg.run_firsts()))
     ]
     if not cfg.tie_embeddings:
         params["lm_head"] = L.dense_init(ks[2], D, V)
     if cfg.enc_dec:
         params["enc"] = {
             "runs": [_init_run(jax.random.fold_in(ks[3], i), cfg, "attn",
-                               cfg.enc_dec.n_enc_layers, False, False)
+                               cfg.enc_dec.n_enc_layers, False, False,
+                               cfg.moe is not None)
                      for i in range(1)],
             "final_norm": jnp.zeros((D,), jnp.float32),
         }
@@ -78,8 +86,15 @@ def init_model(key, cfg: ModelConfig) -> dict:
 # Train / full-sequence forward
 # ---------------------------------------------------------------------- #
 
+def _mlp(p, cfg: ModelConfig, x):
+    """The layer's MLP sublayer: the MoE where its params have a router."""
+    return (L.moe_fwd if "router" in p else L.mlp_fwd)(p, cfg, x)
+
+
 def _layer_fwd(p, cfg: ModelConfig, kind: str, x, enc_out, causal: bool):
-    if kind in ("attn", "local"):
+    if kind == "mla":
+        x = x + L.mla_fwd(p["attn"], cfg, L.rmsnorm(x, p["norm1"]))
+    elif kind in ("attn", "local"):
         window = cfg.window if kind == "local" else None
         if cfg.parallel_block and enc_out is None and not cfg.moe:
             # parallel residual: both sublayer outputs are partial-sums over
@@ -100,8 +115,7 @@ def _layer_fwd(p, cfg: ModelConfig, kind: str, x, enc_out, causal: bool):
     if enc_out is not None:
         x = x + L.attention_fwd(p["xattn"], cfg, L.rmsnorm(x, p["norm_x"]),
                                 kv_src=enc_out)
-    sub = L.moe_fwd if cfg.moe else L.mlp_fwd
-    return x + sub(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
+    return x + _mlp(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
 
 
 def _run_fwd(stacked, cfg: ModelConfig, kind: str, x, enc_out, causal: bool):
@@ -213,6 +227,11 @@ def init_cache(cfg: ModelConfig, B: int, s_max: int, src_len: int = 0) -> list:
                 ent["xk"] = jnp.zeros((n, B, src_len, Hkv, hd), jnp.bfloat16)
                 ent["xv"] = jnp.zeros((n, B, src_len, Hkv, hd), jnp.bfloat16)
             cache.append(ent)
+        elif kind == "mla":
+            dt = jnp.dtype(cfg.dtype)
+            cache.append({
+                "c": jnp.zeros((n, B, s_max, cfg.mla.kv_lora_rank), dt),
+                "pe": jnp.zeros((n, B, s_max, cfg.mla.qk_rope_dim), dt)})
         elif kind == "rglru":
             cache.append({"h": jnp.zeros((n, B, R), jnp.float32),
                           "conv": jnp.zeros((n, B, 3, R), jnp.bfloat16)})
@@ -226,6 +245,11 @@ def init_cache(cfg: ModelConfig, B: int, s_max: int, src_len: int = 0) -> list:
 
 def _layer_prefill(p, cfg, kind, x, enc_out, keep_full=False):
     """Returns (x_out, cache_entry) for one layer."""
+    if kind == "mla":
+        y, c, k_pe = L.mla_prefill(p["attn"], cfg, L.rmsnorm(x, p["norm1"]))
+        x = x + y
+        x = x + _mlp(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
+        return x, {"c": c, "pe": k_pe}
     if kind in ("attn", "local"):
         window = cfg.window if kind == "local" else None
         y, ck, cv = L.attention_prefill(p["attn"], cfg,
@@ -237,14 +261,12 @@ def _layer_prefill(p, cfg, kind, x, enc_out, keep_full=False):
             ent["xk"], ent["xv"] = L.cross_kv(p["xattn"], cfg, enc_out)
             x = x + L.attention_fwd(p["xattn"], cfg, L.rmsnorm(x, p["norm_x"]),
                                     kv_src=enc_out)
-        sub = L.moe_fwd if cfg.moe else L.mlp_fwd
-        x = x + sub(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
+        x = x + _mlp(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
         return x, ent
     if kind == "rglru":
         y, h, conv = L.rglru_prefill(p["rec"], cfg, L.rmsnorm(x, p["norm1"]))
         x = x + y
-        sub = L.moe_fwd if cfg.moe else L.mlp_fwd
-        x = x + sub(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
+        x = x + _mlp(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
         return x, {"h": h, "conv": conv.astype(jnp.bfloat16)}
     if kind == "rwkv6":
         xn = L.rmsnorm(x, p["norm1"])
@@ -295,6 +317,9 @@ def prefill(params, cfg: ModelConfig, inputs: dict, s_max: int, *,
                 pad = [(0, 0), (0, 0), (0, tgt - s_c), (0, 0), (0, 0)]
                 ents["k"] = jnp.pad(ents["k"], pad)
                 ents["v"] = jnp.pad(ents["v"], pad)
+        elif kind == "mla" and S < s_max:
+            ents = {k: jnp.pad(v, [(0, 0), (0, 0), (0, s_max - S), (0, 0)])
+                    for k, v in ents.items()}
         cache.append(ents)
     x = L.rmsnorm(x, params["final_norm"])
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -307,6 +332,12 @@ def prefill(params, cfg: ModelConfig, inputs: dict, s_max: int, *,
 
 
 def _layer_decode(p, cfg, kind, x, ent, pos):
+    if kind == "mla":
+        y, c, k_pe = L.mla_decode(p["attn"], cfg, L.rmsnorm(x, p["norm1"]),
+                                  ent["c"], ent["pe"], pos)
+        x = x + y
+        x = x + _mlp(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
+        return x, {"c": c, "pe": k_pe}
     if kind in ("attn", "local"):
         y, ck, cv = L.attention_decode(p["attn"], cfg, L.rmsnorm(x, p["norm1"]),
                                        ent["k"], ent["v"], pos,
@@ -317,15 +348,13 @@ def _layer_decode(p, cfg, kind, x, ent, pos):
             x = x + L.cross_attention_decode(p["xattn"], cfg,
                                              L.rmsnorm(x, p["norm_x"]),
                                              ent["xk"], ent["xv"])
-        sub = L.moe_fwd if cfg.moe else L.mlp_fwd
-        x = x + sub(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
+        x = x + _mlp(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
         return x, ent
     if kind == "rglru":
         y, h, conv = L.rglru_decode(p["rec"], cfg, L.rmsnorm(x, p["norm1"]),
                                     ent["h"], ent["conv"].astype(jnp.bfloat16))
         x = x + y
-        sub = L.moe_fwd if cfg.moe else L.mlp_fwd
-        x = x + sub(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
+        x = x + _mlp(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
         return x, {"h": h, "conv": conv.astype(jnp.bfloat16)}
     if kind == "rwkv6":
         xn = L.rmsnorm(x, p["norm1"])
@@ -364,13 +393,17 @@ def decode_step(params, cfg: ModelConfig, cache: list, tokens, pos):
 # Paged serving: block-pool cache / per-request-position decode
 # ---------------------------------------------------------------------- #
 
+PAGED_KINDS = ("attn", "local", "mla")
+
+
 def paged_arch_check(cfg: ModelConfig) -> None:
-    """Paged serving covers pure-attention stacks (attn/local, no enc-dec).
+    """Paged serving covers pure-attention stacks (attn/local/mla, no
+    enc-dec).
 
     Recurrent kinds (rglru/rwkv6) carry positionless state that right-padded
     variable-length prefill would corrupt, and enc-dec needs per-request
     encoder outputs — neither fits the shared-pool layout."""
-    bad = [k for k, _ in cfg.runs() if k not in ("attn", "local")]
+    bad = [k for k, _ in cfg.runs() if k not in PAGED_KINDS]
     if bad or cfg.enc_dec:
         raise ValueError(
             f"paged serving supports attention-only decoder stacks; "
@@ -378,7 +411,12 @@ def paged_arch_check(cfg: ModelConfig) -> None:
 
 
 def init_paged_pools(cfg: ModelConfig, n_blocks: int, block_size: int) -> list:
-    """One k/v pool pair per run: (run, n_blocks, block_size, Hkv, hd).
+    """One pool set per run: a k/v pair (run, n_blocks, block_size, Hkv,
+    hd) for attn/local runs; for mla runs the latents ``c`` (run, n_blocks,
+    block_size, kv_lora_rank) and rope keys ``pe`` (run, n_blocks,
+    block_size * qk_rope_dim) in ``cfg.dtype``: kv_lora_rank +
+    qk_rope_dim values a token a layer, no head axis (the layout is
+    ``layers.paged_mla_decode``'s).
 
     Physical block 0 is reserved as the null block — allocators must never
     hand it to a request, so inactive batch slots (block table all-zero) can
@@ -387,6 +425,13 @@ def init_paged_pools(cfg: ModelConfig, n_blocks: int, block_size: int) -> list:
     hd, Hkv = cfg.head_dim, cfg.n_kv_heads
     pools = []
     for kind, n in cfg.runs():
+        if kind == "mla":
+            a, dt = cfg.mla, jnp.dtype(cfg.dtype)
+            pools.append({
+                "c": jnp.zeros((n, n_blocks, block_size, a.kv_lora_rank), dt),
+                "pe": jnp.zeros((n, n_blocks, block_size * a.qk_rope_dim),
+                                dt)})
+            continue
         shape = (n, n_blocks, block_size, Hkv, hd)
         pools.append({"k": jnp.zeros(shape, jnp.bfloat16),
                       "v": jnp.zeros(shape, jnp.bfloat16)})
@@ -398,35 +443,43 @@ def scatter_prefill_cache(pools: list, cache: list, blocks, block_size: int,
     """Copy one request's dense prefill cache (from ``prefill`` with
     ``full_local_cache=True``) into its allocated physical blocks.
 
-    cache entries: (run, B, S_p, Hkv, hd) with S_p % block_size == 0;
+    cache entries: (run, B, S_p, ...) — k and v (..., Hkv, hd), or mla's
+    latents c and rope keys pe — with S_p % block_size == 0, each laid into
+    its pool's blocks;
     ``blocks``: the request's physical block ids, len == S_p // block_size.
     Returns the updated pools list."""
     blocks = jnp.asarray(blocks, jnp.int32)
     out = []
     for pool, ent in zip(pools, cache):
-        n, _, S_p, Hkv, hd = ent["k"].shape
-        if S_p % block_size:
-            raise ValueError(f"prefill length {S_p} not a multiple of "
-                             f"block_size {block_size}")
-        nb = S_p // block_size
-        if nb != len(blocks):
-            raise ValueError(f"need {nb} blocks, got {len(blocks)}")
-        kk = ent["k"][:, row].reshape(n, nb, block_size, Hkv, hd)
-        vv = ent["v"][:, row].reshape(n, nb, block_size, Hkv, hd)
-        out.append({"k": pool["k"].at[:, blocks].set(kk),
-                    "v": pool["v"].at[:, blocks].set(vv)})
+        new = {}
+        for name in pool:
+            n, _, S_p = ent[name].shape[:3]
+            if S_p % block_size:
+                raise ValueError(f"prefill length {S_p} not a multiple of "
+                                 f"block_size {block_size}")
+            nb = S_p // block_size
+            if nb != len(blocks):
+                raise ValueError(f"need {nb} blocks, got {len(blocks)}")
+            rows = ent[name][:, row].reshape(n, nb, *pool[name].shape[2:])
+            new[name] = pool[name].at[:, blocks].set(rows)
+        out.append(new)
     return out
 
 
 def _layer_decode_paged(p, cfg, kind, x, ent, layer, block_tables, pos):
-    window = cfg.window if kind == "local" else None
-    y, pk, pv = L.paged_attention_decode(
-        p["attn"], cfg, L.rmsnorm(x, p["norm1"]), ent["k"], ent["v"], layer,
-        block_tables, pos, window=window)
+    xn = L.rmsnorm(x, p["norm1"])
+    if kind == "mla":
+        y, c, k_pe = L.paged_mla_decode(p["attn"], cfg, xn, ent["c"],
+                                        ent["pe"], layer, block_tables, pos)
+        ent = {"c": c, "pe": k_pe}
+    else:
+        window = cfg.window if kind == "local" else None
+        y, pk, pv = L.paged_attention_decode(
+            p["attn"], cfg, xn, ent["k"], ent["v"], layer, block_tables, pos,
+            window=window)
+        ent = {"k": pk, "v": pv}
     x = x + y
-    sub = L.moe_fwd if cfg.moe else L.mlp_fwd
-    x = x + sub(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]))
-    return x, {"k": pk, "v": pv}
+    return x + _mlp(p["mlp"], cfg, L.rmsnorm(x, p["norm2"])), ent
 
 
 def decode_step_paged(params, cfg: ModelConfig, pools: list, block_tables,

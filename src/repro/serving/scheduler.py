@@ -119,12 +119,20 @@ class JaxExecutor:
     per length) with ``full_local_cache=True`` and the dense result is
     scattered into the request's physical blocks; decode is one
     ``decode_step_paged`` over the whole slot table with per-slot positions
-    — idle slots write to the null block and are ignored.  Each decode
+    — slots not decoded write to the null block and are ignored.  Each decode
     donates ``pools`` to the step, which writes the new rows in place: a
-    pool array read before a decode is deleted by it."""
+    pool array read before a decode is deleted by it.
+
+    ``metrics`` (a :class:`~repro.obs.MetricsRegistry`, else one of its
+    own) counts, on the host, per decode call: ``repro.decode.calls``, and
+    for latent (mla) pools the rows the step gathers from the block tables
+    (``repro.mla.latent_rows_gathered``: every slot's whole table) and the
+    rows that hold a token of a decoded slot (``repro.mla.latent_rows_live``),
+    one row per token per mla layer."""
 
     def __init__(self, cfg, mesh, *, n_blocks: int, block_size: int,
-                 max_slots: int, max_blocks: int, seed: int = 0):
+                 max_slots: int, max_blocks: int, seed: int = 0,
+                 metrics: MetricsRegistry | None = None):
         import jax
         import jax.numpy as jnp
         from repro.models import transformer as T
@@ -140,6 +148,9 @@ class JaxExecutor:
         self.params = T.init_model(jax.random.PRNGKey(seed), cfg)
         self.pools = T.init_paged_pools(cfg, n_blocks, block_size)
         self.tables = np.zeros((max_slots, max_blocks), np.int32)
+        self.metrics = metrics if metrics is not None else MetricsRegistry()
+        self._latent_layers = sum(n for kind, n in cfg.runs()
+                                  if kind == "mla")
         self._prefills: dict[int, object] = {}
         import functools
         self._decode = jax.jit(functools.partial(T.decode_step_paged, cfg=cfg),
@@ -183,11 +194,16 @@ class JaxExecutor:
         with span("repro.decode.inputs"):
             tok = np.zeros((self.max_slots, 1), np.int32)
             posv = np.zeros((self.max_slots,), np.int32)
+            # a slot not decoded this call keeps an all-null table row, so
+            # its dummy token writes the null block and not a live cache
+            tables = np.zeros_like(self.tables)
             for s, t, p in zip(slots, tokens, pos):
                 tok[s, 0] = t
                 posv[s] = p
-            tables = jnp.asarray(self.tables)
+                tables[s] = self.tables[s]
+            tables = jnp.asarray(tables)
             tok, posv = jnp.asarray(tok), jnp.asarray(posv)
+            self._count(pos)
         with span("repro.decode.launch"):
             logits, self.pools = self._decode(
                 params=self.params, pools=self.pools,
@@ -197,6 +213,15 @@ class JaxExecutor:
         with span("repro.decode.readback"):
             out = np.asarray(nxt)
             return [int(out[s]) for s in slots]
+
+    def _count(self, pos) -> None:
+        self.metrics.counter("repro.decode.calls").inc()
+        if self._latent_layers:
+            n = self._latent_layers
+            self.metrics.counter("repro.mla.latent_rows_gathered").inc(
+                n * self.tables.size * self.block_size)
+            self.metrics.counter("repro.mla.latent_rows_live").inc(
+                n * sum(int(p) + 1 for p in pos))
 
     def release(self, slot):
         self.tables[slot, :] = 0

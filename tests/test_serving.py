@@ -506,3 +506,108 @@ def test_served_path_spans_land_in_the_profilers_trace(tmp_path):
     assert len(step.findall(names)) == rep.steps
     assert step.sub("", names) == ""
     assert names.count(decode) == dec.call_count > 0
+
+
+# ---------------------------------------------------------------------- #
+# DeepSeek-V2: the latent pool and the absorbed decode
+# ---------------------------------------------------------------------- #
+
+def _mla_cfg(held=None, dtype="bfloat16"):
+    import dataclasses
+    cfg = get_config("deepseek_v2_lite_16b", smoke=True)
+    return dataclasses.replace(
+        cfg, dtype=dtype, moe=dataclasses.replace(cfg.moe, n_held=held))
+
+
+def test_latent_pool_holds_576_values_a_token_a_layer():
+    """At DeepSeek-V2-Lite's widths each run's pool holds, per layer and
+    token, the 512-wide latent and the 64-wide rope key and nothing else:
+    no head axis, 27 x 576 bfloat16 values a token."""
+    cfg = get_config("deepseek_v2_lite_16b")
+    nb, BS = 2065, 16
+    pools = jax.eval_shape(lambda: T.init_paged_pools(cfg, nb, BS))
+    assert [(n, {k: v.shape for k, v in p.items()})
+            for (_, n), p in zip(cfg.runs(), pools)] == [
+        (n, {"c": (n, nb, BS, 512), "pe": (n, nb, BS * 64)})
+        for n in (1, 26)]
+    per_token = sum(x.size for x in jax.tree.leaves(pools)) // (nb * BS)
+    assert per_token == 27 * 576
+    assert {x.dtype for x in jax.tree.leaves(pools)} == {jnp.dtype("bfloat16")}
+
+
+def _avals(jaxpr):
+    """Every value a jaxpr and the jaxprs inside it compute."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _avals(sub)
+
+
+def _holds_heads_of_context(avals, S, H, d):
+    """Whether some value has a heads axis and as many values as every
+    head's key or value of an S-token context."""
+    return any(H in getattr(a, "shape", ()) and a.size >= S * H * d
+               for a in avals)
+
+
+def test_absorbed_decode_never_decompresses_the_cache():
+    """The paged decode step reads only latent rows: no value in it holds
+    per-head keys or values of the context, while the prefill, which
+    decompresses them, does."""
+    cfg = _mla_cfg()
+    H, a = cfg.n_heads, cfg.mla
+    d = min(a.qk_nope_dim, a.v_head_dim)
+    BS, MB = 8, 32  # the context outweighs any weight with a heads axis
+    S = MB * BS
+    params = jax.eval_shape(lambda: T.init_model(KEY, cfg))
+    pools = jax.eval_shape(lambda: T.init_paged_pools(cfg, 1 + MB, BS))
+    i32 = jnp.int32
+    decode = jax.make_jaxpr(lambda p, pl: T.decode_step_paged(
+        p, cfg, pl, jnp.zeros((1, MB), i32), jnp.zeros((1, 1), i32),
+        jnp.zeros((1,), i32)))(params, pools)
+    prefill = jax.make_jaxpr(lambda p: T.prefill(
+        p, cfg, {"tokens": jnp.zeros((1, S), i32)}, S))(params)
+    assert not _holds_heads_of_context(_avals(decode.jaxpr), S, H, d)
+    assert _holds_heads_of_context(_avals(prefill.jaxpr), S, H, d)
+
+
+def test_jax_executor_serves_mla_with_latent_counters():
+    """The scheduler over the real executor serves the MLA stack (a share
+    of 2 of 8 experts held) through its latent pools: every request's
+    tokens are a standalone dense prefill + decode loop's greedy tokens,
+    and the executor counts the latent rows each decode call gathers and
+    the rows that hold a decoded slot's tokens."""
+    cfg = _mla_cfg(held=2, dtype="float32")
+    BS, s_max, slots = 4, 24, 2
+    reqs = make_requests([0.0] * 3, vocab=cfg.vocab, prompt_len=4,
+                         gen_len=4, seed=0)
+    rng = np.random.default_rng(0)
+    for r, n, g in zip(reqs, [3, 8, 5], [6, 3, 5]):
+        r.prompt = rng.integers(0, cfg.vocab, (n,)).astype(np.int32)
+        r.max_new_tokens = g
+    ex = JaxExecutor(cfg, None, n_blocks=1 + slots * (s_max // BS),
+                     block_size=BS, max_slots=slots,
+                     max_blocks=s_max // BS, seed=0)
+    ex.params = jax.tree.map(lambda x: x.astype(jnp.float32), ex.params)
+    calls, live = [], 0
+    decode = ex.decode
+
+    def counted(slots_, tokens, pos):
+        nonlocal live
+        calls.append(len(slots_))
+        live += sum(p + 1 for p in pos)
+        return decode(slots_, tokens, pos)
+
+    ex.decode = counted
+    sch = Scheduler(ex, n_blocks=1 + slots * (s_max // BS), block_size=BS,
+                    max_slots=slots, s_max=s_max, prefill_token_budget=16)
+    sch.run(reqs)
+    assert all(r.state is ReqState.DONE for r in reqs)
+    for r in reqs:
+        assert r.tokens == _dense_greedy(cfg, ex.params, r.prompt,
+                                         r.max_new_tokens), r.rid
+    m = ex.metrics
+    assert m.counter("repro.decode.calls").value == len(calls)
+    assert m.counter("repro.mla.latent_rows_gathered").value == \
+        len(calls) * 3 * slots * s_max
+    assert m.counter("repro.mla.latent_rows_live").value == 3 * live
