@@ -906,21 +906,39 @@ def _route(p, m, xt):
     return gates, eidx
 
 
+EXPERT_KEYS = ("w_in", "w_gate", "w_out")
+
+
+def _local_experts(m, eidx):
+    """(flat local expert ids (T*k,), mask of held choices or None).
+
+    Where this device holds a share of the experts, ``[first_held,
+    first_held + held)``, a choice of another expert gets id ``held``, so
+    that it sorts after the held ones."""
+    flat_e = eidx.reshape(-1)
+    if m.held == m.n_experts:
+        return flat_e, None
+    local = flat_e - m.first_held
+    mine = (local >= 0) & (local < m.held)
+    return jnp.where(mine, local, m.held), mine
+
+
+def _combine(gates, yo):
+    """Each token's (T*k, D) choice outputs, token-major, weighted by its
+    gates: (T, D)."""
+    T, k = gates.shape
+    yo = yo.reshape(T, k, yo.shape[-1])
+    return jnp.einsum("tk,tkd->td", gates.astype(yo.dtype), yo)
+
+
 def _moe_block(p, m, xt):
     """Route + dispatch + expert compute for one block of tokens (T, D).
 
     Dropless: the dispatch buffer has a row for every (token, choice).
-    Where this device holds a share of the experts, ``[first_held,
-    first_held + held)``, choices of other experts sort after the held
-    groups, which ``ragged_dot`` leaves out, and are masked off."""
-    T, D = xt.shape
+    Choices of experts not held here sort after the held groups, which
+    ``ragged_dot`` leaves out, and are masked off."""
     gates, eidx = _route(p, m, xt)                 # (T,k)
-    flat_e = eidx.reshape(-1)                      # (T*k,)
-    share = m.held != m.n_experts
-    if share:
-        local = flat_e - m.first_held
-        mine = (local >= 0) & (local < m.held)
-        flat_e = jnp.where(mine, local, m.held)    # other experts sort last
+    flat_e, mine = _local_experts(m, eidx)         # (T*k,)
     order = jnp.argsort(flat_e)                    # stable sort by expert
     tok_for = order // m.top_k                     # token index per slot
     xs = xt[tok_for]                               # (T*k, D) sorted by expert
@@ -930,10 +948,37 @@ def _moe_block(p, m, xt):
     h = jax.nn.silu(g) * h
     yo = lax.ragged_dot(h, p["w_out"], group_sizes)  # (T*k, D)
     yo = yo[jnp.argsort(order)]
-    if share:
+    if mine is not None:
         yo = jnp.where(mine[:, None], yo, 0)
-    yo = yo.reshape(T, m.top_k, D)
-    return jnp.einsum("tk,tkd->td", gates.astype(yo.dtype), yo)
+    return _combine(gates, yo)
+
+
+def _moe_chosen(p, m, xt, layer):
+    """``_moe_block`` for a few tokens, each (token, choice) computed from
+    its held expert's weights in the stacked run (``p``'s w_* are (layers,
+    held, ...)), read by index at ``layer``: no layer's expert set is
+    sliced out, and no expert not chosen is read."""
+    from repro.kernels.chosen_experts import chosen_expert_matmul
+
+    gates, eidx = _route(p, m, xt)
+    flat_e, _ = _local_experts(m, eidx)
+    order = jnp.argsort(flat_e)
+    yo = _replicated(chosen_expert_matmul)(
+        xt[order // m.top_k], *(p[k] for k in EXPERT_KEYS), layer,
+        flat_e[order])
+    return _combine(gates, yo[jnp.argsort(order)])
+
+
+def _replicated(kernel):
+    """``kernel`` inside a shard_map naming every mesh axis, all operands
+    replicated, where the caller left any axis ``Auto``: GSPMD cannot
+    partition a Mosaic kernel."""
+    am = jax.sharding.get_abstract_mesh()
+    if all(t == jax.sharding.AxisType.Manual for t in am.axis_types):
+        return kernel
+    return jax.shard_map(kernel, in_specs=jax.sharding.PartitionSpec(),
+                         out_specs=jax.sharding.PartitionSpec(),
+                         axis_names=set(am.axis_names), check_vma=False)
 
 
 def _moe_block_ep(p, m, xt, axis: str):
@@ -979,14 +1024,39 @@ def _moe_block_ep(p, m, xt, axis: str):
     return lax.psum(out, axis).astype(xt.dtype)
 
 
-def moe_fwd(p, cfg: ModelConfig, x, chunk: int = MOE_CHUNK) -> jax.Array:
+def expert_parallel(m, mesh) -> bool:
+    """Whether ``moe_fwd`` splits the experts over ``mesh``'s ``model``
+    axis (an abstract or concrete mesh, or None): the axis exists, is
+    wider than 1 and divides the experts, and all of them are held."""
+    if mesh is None or "model" not in mesh.axis_names:
+        return False
+    msize = mesh.shape["model"]
+    return msize > 1 and m.n_experts % msize == 0 and m.held == m.n_experts
+
+
+def reads_chosen_experts(m, T: int, mesh) -> bool:
+    """Whether ``moe_fwd`` over T tokens, handed a run's stacked experts
+    and a layer index, computes each (token, choice) from its held
+    expert's stacked weights (``_moe_chosen``): while the choices are no
+    more than the experts held, that reads fewer bytes than the layer's
+    whole held set.  Expert parallelism keeps its own path."""
+    return T * m.top_k <= m.held and not expert_parallel(m, mesh)
+
+
+def moe_fwd(p, cfg: ModelConfig, x, chunk: int = MOE_CHUNK,
+            layer=None) -> jax.Array:
     """Top-k MoE via sort + lax.ragged_dot (MegaBlocks-style).
 
-    Two data paths:
+    Three data paths:
       * explicit expert parallelism (shard_map manual over `model`) when a
         model axis exists and divides n_experts — local capacity-bounded
         dispatch, one combine psum;
-      * single-device ragged path otherwise (tests, no-TP meshes).
+      * with ``layer`` given (``p``'s w_* then stacked over a run's layers)
+        and few tokens (``reads_chosen_experts``), each choice read from
+        its held expert in the stacked run by the ``chosen_expert_matmul``
+        kernel;
+      * single-device ragged path otherwise (tests, no-TP meshes), on
+        ``layer``'s slice of stacked weights.
     Long sequences are scanned in token blocks with remat: dispatch buffers
     live only per block (8x working-set cut at olmoe prefill_32k)."""
     import jax.sharding as jsh
@@ -998,34 +1068,37 @@ def moe_fwd(p, cfg: ModelConfig, x, chunk: int = MOE_CHUNK) -> jax.Array:
 
     block = None
     am = jsh.get_abstract_mesh()
-    if "model" in am.axis_names:
-        msize = am.shape["model"]
-        if msize > 1 and m.n_experts % msize == 0 and m.held == m.n_experts:
-            # dp axes still in AUTO state (e.g. the GSPMD serving path) must
-            # become manual alongside `model`, with tokens sharded over them
-            # — otherwise the P() token spec would force an all-gather of
-            # the whole global batch onto every device.
-            auto_dp = tuple(
-                n for n, t in zip(am.axis_names, am.axis_types)
-                if n in ("pod", "data") and "Auto" in str(t))
-            manual = {"model", *auto_dp}
-            tok_spec = (jsh.PartitionSpec(auto_dp, None) if auto_dp
-                        else jsh.PartitionSpec())
-            especs = {
-                "router": jsh.PartitionSpec(),
-                "w_in": jsh.PartitionSpec("model", None, None),
-                "w_gate": jsh.PartitionSpec("model", None, None),
-                "w_out": jsh.PartitionSpec("model", None, None),
-            }
-            if m.shared_expert:
-                especs["shared"] = jax.tree.map(
-                    lambda _: jsh.PartitionSpec(), p["shared"])
-            ep = jax.shard_map(
-                lambda pp, xb: _moe_block_ep(pp, m, xb, "model"),
-                in_specs=(especs, tok_spec),
-                out_specs=tok_spec,
-                axis_names=manual, check_vma=False)
-            block = lambda xb: ep(p, xb)
+    if layer is not None:
+        if reads_chosen_experts(m, T, am):
+            block = lambda xb: _moe_chosen(p, m, xb, layer)
+        else:
+            p = dict(p, **{k: p[k][layer] for k in EXPERT_KEYS})
+    if block is None and expert_parallel(m, am):
+        # dp axes still in AUTO state (e.g. the GSPMD serving path) must
+        # become manual alongside `model`, with tokens sharded over them
+        # — otherwise the P() token spec would force an all-gather of
+        # the whole global batch onto every device.
+        auto_dp = tuple(
+            n for n, t in zip(am.axis_names, am.axis_types)
+            if n in ("pod", "data") and "Auto" in str(t))
+        manual = {"model", *auto_dp}
+        tok_spec = (jsh.PartitionSpec(auto_dp, None) if auto_dp
+                    else jsh.PartitionSpec())
+        especs = {
+            "router": jsh.PartitionSpec(),
+            "w_in": jsh.PartitionSpec("model", None, None),
+            "w_gate": jsh.PartitionSpec("model", None, None),
+            "w_out": jsh.PartitionSpec("model", None, None),
+        }
+        if m.shared_expert:
+            especs["shared"] = jax.tree.map(
+                lambda _: jsh.PartitionSpec(), p["shared"])
+        ep = jax.shard_map(
+            lambda pp, xb: _moe_block_ep(pp, m, xb, "model"),
+            in_specs=(especs, tok_spec),
+            out_specs=tok_spec,
+            axis_names=manual, check_vma=False)
+        block = lambda xb: ep(p, xb)
     if block is None:
         block = lambda xb: _moe_block(p, m, xb)
 

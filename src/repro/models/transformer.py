@@ -86,9 +86,12 @@ def init_model(key, cfg: ModelConfig) -> dict:
 # Train / full-sequence forward
 # ---------------------------------------------------------------------- #
 
-def _mlp(p, cfg: ModelConfig, x):
-    """The layer's MLP sublayer: the MoE where its params have a router."""
-    return (L.moe_fwd if "router" in p else L.mlp_fwd)(p, cfg, x)
+def _mlp(p, cfg: ModelConfig, x, layer=None):
+    """The layer's MLP sublayer: the MoE where its params have a router
+    (with ``layer``, its experts stacked over the run: ``L.moe_fwd``)."""
+    if "router" in p:
+        return L.moe_fwd(p, cfg, x, layer=layer)
+    return L.mlp_fwd(p, cfg, x)
 
 
 def _layer_fwd(p, cfg: ModelConfig, kind: str, x, enc_out, causal: bool):
@@ -479,7 +482,7 @@ def _layer_decode_paged(p, cfg, kind, x, ent, layer, block_tables, pos):
             window=window)
         ent = {"k": pk, "v": pv}
     x = x + y
-    return x + _mlp(p["mlp"], cfg, L.rmsnorm(x, p["norm2"])), ent
+    return x + _mlp(p["mlp"], cfg, L.rmsnorm(x, p["norm2"]), layer), ent
 
 
 def decode_step_paged(params, cfg: ModelConfig, pools: list, block_tables,
@@ -490,13 +493,24 @@ def decode_step_paged(params, cfg: ModelConfig, pools: list, block_tables,
 
     Each run's pools ride the scan's carry, so a layer writes its new rows
     into them and reads its keys and values from them in place; jit the
-    step with ``pools`` donated to keep a second set of pools out of it."""
+    step with ``pools`` donated to keep a second set of pools out of it.
+    A MoE run's expert weights are not scanned: each layer gets them
+    stacked, with its index, so that it reads only its chosen experts
+    (``L.moe_fwd``)."""
     x = params["embed"][tokens] * math.sqrt(cfg.d_model)
     new_pools = []
     for stacked, ent, (kind, n) in zip(params["runs"], pools, cfg.runs()):
-        def step(carry, p_layer, kind=kind):
+        experts = {}
+        if "router" in stacked["mlp"]:
+            mlp = dict(stacked["mlp"])
+            experts = {k: mlp.pop(k) for k in L.EXPERT_KEYS}
+            stacked = dict(stacked, mlp=mlp)
+
+        def step(carry, p_layer, kind=kind, experts=experts):
             x, e = carry
             p, layer = p_layer
+            if experts:
+                p = dict(p, mlp=dict(p["mlp"], **experts))
             return _layer_decode_paged(p, cfg, kind, x, e, layer,
                                        block_tables, pos), None
 

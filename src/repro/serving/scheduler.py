@@ -128,13 +128,16 @@ class JaxExecutor:
     for latent (mla) pools the rows the step gathers from the block tables
     (``repro.mla.latent_rows_gathered``: every slot's whole table) and the
     rows that hold a token of a decoded slot (``repro.mla.latent_rows_live``),
-    one row per token per mla layer."""
+    one row per token per mla layer; and ``repro.moe.chosen_expert_calls``
+    where the step's MoE layers read only their chosen experts
+    (``layers.reads_chosen_experts`` at ``max_slots`` tokens)."""
 
     def __init__(self, cfg, mesh, *, n_blocks: int, block_size: int,
                  max_slots: int, max_blocks: int, seed: int = 0,
                  metrics: MetricsRegistry | None = None):
         import jax
         import jax.numpy as jnp
+        from repro.models import layers as L
         from repro.models import transformer as T
 
         T.paged_arch_check(cfg)
@@ -151,6 +154,9 @@ class JaxExecutor:
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self._latent_layers = sum(n for kind, n in cfg.runs()
                                   if kind == "mla")
+        self._chosen_experts = (
+            any(map(cfg.layer_moe, range(cfg.n_layers)))
+            and L.reads_chosen_experts(cfg.moe, max_slots, mesh))
         self._prefills: dict[int, object] = {}
         import functools
         self._decode = jax.jit(functools.partial(T.decode_step_paged, cfg=cfg),
@@ -216,6 +222,8 @@ class JaxExecutor:
 
     def _count(self, pos) -> None:
         self.metrics.counter("repro.decode.calls").inc()
+        if self._chosen_experts:
+            self.metrics.counter("repro.moe.chosen_expert_calls").inc()
         if self._latent_layers:
             n = self._latent_layers
             self.metrics.counter("repro.mla.latent_rows_gathered").inc(

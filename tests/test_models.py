@@ -299,3 +299,178 @@ def test_llama4_shared_expert_keeps_the_dense_width():
         assert shared["wi"].shape[-1] == cfg.d_ff
         assert cfg.param_count() == n
         assert sum(x.size for x in jax.tree.leaves(shapes)) == n
+
+
+# ---------------------------------------------------------------------- #
+# The decode MoE: each (token, choice) read from its held expert by index
+# ---------------------------------------------------------------------- #
+
+# (held, first_held, router): "foreign" and "held" steer every choice off
+# or onto the held experts with router columns of -1 or +1 against
+# inputs made positive (their logits then sit about 0.8 D below or above the
+# rest's).  The layer's outputs are about 0.05 here, so the float32 paths
+# (apart by the order of sums) are held to a tenth of TOL, which the same
+# layer in bfloat16 fails.
+CHOICE_TOL = TOL / 10
+CHOICE_CASES = {"all-held": (None, 0, None),
+                "held-2-of-8": (2, 2, None),
+                "every-choice-foreign": (2, 2, -1.0),
+                "every-choice-held": (2, 2, 1.0)}
+
+
+def _moe_layer(cfg, layer, router=None):
+    """(one layer's MoE params, the same with the run's stacked experts)
+    of the smoke stack's MoE run, in float32."""
+    run = _f32(T.init_model(KEY, cfg))["runs"][1]["mlp"]
+    p = jax.tree.map(lambda a: a[layer], run)
+    if router is not None:
+        m = cfg.moe
+        p["router"] = p["router"].at[:, m.first_held:m.first_held + m.held
+                                     ].set(router)
+    return p, dict(p, **{k: run[k] for k in L.EXPERT_KEYS})
+
+
+@pytest.mark.parametrize("n_tok", [1, 2], ids=["T1", "T2"])
+@pytest.mark.parametrize("case", list(CHOICE_CASES))
+def test_chosen_experts_match_the_ragged_path(case, n_tok):
+    """The chosen-expert path, reading each choice's expert from the
+    stacked run at a layer index, gives the ragged dots' output on that
+    layer's slice: with all experts held, a share, every choice off the
+    share (nothing but zeros routed, the shared experts alone) and every
+    choice on it."""
+    held, first, router = CHOICE_CASES[case]
+    cfg = _mla_cfg(held, first)
+    m, layer = cfg.moe, 1
+    p, stacked = _moe_layer(cfg, layer, router)
+    # at this key the share gets some of the choices, at T 1 and 2
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, n_tok, cfg.d_model))
+    if router is not None:
+        x = jnp.abs(x)
+    xt = x[0]
+    local = L._route(p, m, xt)[1] - m.first_held
+    mine = np.asarray((local >= 0) & (local < m.held))
+    assert {"every-choice-foreign": not mine.any(),
+            "held-2-of-8": mine.any() and not mine.all()}.get(
+        case, mine.all())
+    got = L._moe_chosen(stacked, m, xt, jnp.int32(layer))
+    want = L._moe_block(p, m, xt)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=CHOICE_TOL)
+    y = L.moe_fwd(stacked, cfg, x, layer=jnp.int32(layer))
+    if case == "every-choice-foreign":
+        assert not np.asarray(got).any()
+        np.testing.assert_array_equal(
+            np.asarray(y), np.asarray(L.mlp_fwd(p["shared"], cfg, x)))
+    else:
+        low = L._moe_block(jax.tree.map(lambda a: a.astype(jnp.bfloat16), p),
+                           m, xt.astype(jnp.bfloat16))
+        assert np.abs(np.asarray(low, np.float32) - np.asarray(want)).max() \
+            > CHOICE_TOL
+    np.testing.assert_allclose(np.asarray(y), np.asarray(L.moe_fwd(p, cfg, x)),
+                               rtol=0, atol=CHOICE_TOL)
+
+
+@pytest.mark.parametrize("held, first", [(None, 0), (2, 2)],
+                         ids=["all-experts", "held-2-of-8"])
+def test_paged_decode_with_chosen_experts_matches_the_ragged_path(held,
+                                                                  first):
+    """The whole paged decode step over the latent pools: one slot, whose
+    MoE layers take the chosen-expert path, gives the logits that slot gets
+    in a step of enough slots (the others idle on the null block) for the
+    ragged dots to take over, at every decoded position."""
+    cfg = _mla_cfg(held, first)
+    m = cfg.moe
+    params = _f32(T.init_model(KEY, cfg))
+    S, n_new, BS = 8, 5, 4
+    wide = m.held // m.top_k + 1
+    assert L.reads_chosen_experts(m, 1, None)
+    assert not L.reads_chosen_experts(m, wide, None)
+    toks = jax.random.randint(KEY, (1, S + n_new), 0, cfg.vocab)
+    max_blocks = -(-(S + n_new) // BS)
+    _, cache, _ = T.prefill(params, cfg, {"tokens": toks[:, :S]}, S,
+                            full_local_cache=True)
+    logits = {}
+    for B in (1, wide):
+        pools = T.scatter_prefill_cache(
+            T.init_paged_pools(cfg, 1 + max_blocks, BS), cache,
+            list(range(1, S // BS + 1)), BS)
+        table = jnp.zeros((B, max_blocks), jnp.int32).at[0].set(
+            jnp.arange(1, max_blocks + 1))
+        out = []
+        for i in range(n_new):
+            tok = jnp.zeros((B, 1), jnp.int32).at[0].set(toks[0, S + i])
+            pos = jnp.zeros((B,), jnp.int32).at[0].set(S + i)
+            lg, pools = T.decode_step_paged(params, cfg, pools, table, tok,
+                                            pos)
+            out.append(np.asarray(lg[0, 0]))
+        logits[B] = np.stack(out)
+    np.testing.assert_allclose(logits[1], logits[wide], rtol=0, atol=TOL)
+
+
+def _kernel_names(jaxpr):
+    """Names of the Pallas kernels a jaxpr and the jaxprs inside it call."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_names(sub)
+
+
+@pytest.mark.parametrize("arch, held, n_tok, kernel", [
+    ("deepseek_v2_lite_16b", 2, 1, True),
+    ("deepseek_v2_lite_16b", 2, 4096, False),
+    ("olmoe_1b_7b", None, 4096, False)],
+    ids=["mla-2-of-8-T1", "mla-2-of-8-T4096", "olmoe-all-held-T4096"])
+def test_moe_path_is_chosen_by_shape(arch, held, n_tok, kernel):
+    """Handed a run's stacked experts and a layer index, ``moe_fwd`` calls
+    the chosen-expert kernel for one token a share of 2 of 8 experts, and
+    the ragged dots on the layer's slice for 4096 tokens and for an
+    OLMoE-shaped layer (64 experts, top-8, all held) at 4096 tokens; only
+    the kernel path holds no value of a layer's whole expert set."""
+    cfg = get_config(arch, smoke=True)
+    cfg = dataclasses.replace(
+        cfg, moe=dataclasses.replace(cfg.moe, n_held=held, first_held=2
+                                     if held else 0))
+    if arch == "olmoe_1b_7b":
+        full = get_config(arch)
+        assert (full.moe.n_experts, full.moe.top_k) == (64, 8)
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+            cfg.moe, n_experts=64, top_k=8))
+    m = cfg.moe
+    run = jax.eval_shape(lambda: T.init_model(KEY, cfg))["runs"][-1]["mlp"]
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+                     run)
+    p.update({k: run[k] for k in L.EXPERT_KEYS})
+    x = jax.ShapeDtypeStruct((1, n_tok, cfg.d_model), jnp.bfloat16)
+    jaxpr = jax.jit(lambda p, x, layer: L.moe_fwd(p, cfg, x, layer=layer)
+                    ).trace(p, x, jax.ShapeDtypeStruct((), jnp.int32)).jaxpr
+    assert (list(_kernel_names(jaxpr.jaxpr)) == ["chosen_expert_matmul"]) \
+        == kernel
+    expert_set = (m.held, cfg.d_model, m.d_ff_expert)
+    assert any(a.shape == expert_set for a in _avals(jaxpr.jaxpr)) \
+        != kernel
+
+
+def _avals(jaxpr):
+    """Every value a jaxpr and the jaxprs inside it compute."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _avals(sub)
+
+
+def test_mla_prefill_lowers_as_before():
+    """The MLA prefill (a share of 2 of 8 experts, 64 tokens) lowers to
+    the text it lowered to before the decode's chosen-expert path existed
+    (sha256 taken with jax 0.9.0): the prefill's MoE keeps the ragged
+    dots."""
+    import hashlib
+    cfg = _mla_cfg(2, 2)
+    cfg = dataclasses.replace(cfg, dtype="bfloat16")
+    params = jax.eval_shape(lambda: T.init_model(KEY, cfg))
+    S = 64
+    txt = jax.jit(lambda p, t: T.prefill(p, cfg, {"tokens": t}, S,
+                                         full_local_cache=True)).lower(
+        params, jax.ShapeDtypeStruct((1, S), jnp.int32)).as_text()
+    assert hashlib.sha256(txt.encode()).hexdigest() == (
+        "c5010d402c43c10fc7dd5b2a4dc07e25615326ca5cdf29da8f1b14493a697b22")

@@ -611,3 +611,42 @@ def test_jax_executor_serves_mla_with_latent_counters():
     assert m.counter("repro.mla.latent_rows_gathered").value == \
         len(calls) * 3 * slots * s_max
     assert m.counter("repro.mla.latent_rows_live").value == 3 * live
+
+
+@pytest.mark.parametrize("arch, held, slots, chosen", [
+    ("deepseek_v2_lite_16b", 2, 1, True),
+    ("deepseek_v2_lite_16b", 2, 2, False),
+    ("qwen3_4b", None, 1, False)],
+    ids=["mla-2-of-8-one-slot", "mla-2-of-8-two-slots", "dense"])
+def test_jax_executor_counts_chosen_expert_calls(arch, held, slots, chosen):
+    """The executor counts ``repro.moe.chosen_expert_calls`` once for each
+    decode call whose step reads only the chosen experts: every call for
+    a share of 2 of 8 experts on one slot (2 choices a step, 2 experts
+    held), none on two slots (4 choices) and none for a dense stack.
+    Every request's tokens are a dense prefill and decode loop's."""
+    cfg = get_config(arch, smoke=True)
+    if held:
+        cfg = _mla_cfg(held=held, dtype="float32")
+    BS, s_max = 4, 24
+    reqs = make_requests([0.0] * 2, vocab=cfg.vocab, prompt_len=4,
+                         gen_len=4, seed=0)
+    rng = np.random.default_rng(1)
+    for r, n, g in zip(reqs, [5, 8], [4, 6]):
+        r.prompt = rng.integers(0, cfg.vocab, (n,)).astype(np.int32)
+        r.max_new_tokens = g
+    ex = JaxExecutor(cfg, None, n_blocks=1 + slots * (s_max // BS),
+                     block_size=BS, max_slots=slots,
+                     max_blocks=s_max // BS, seed=0)
+    if held:
+        ex.params = jax.tree.map(lambda x: x.astype(jnp.float32), ex.params)
+    sch = Scheduler(ex, n_blocks=1 + slots * (s_max // BS), block_size=BS,
+                    max_slots=slots, s_max=s_max, prefill_token_budget=16)
+    sch.run(reqs)
+    assert all(r.state is ReqState.DONE for r in reqs)
+    for r in reqs:
+        assert r.tokens == _dense_greedy(cfg, ex.params, r.prompt,
+                                         r.max_new_tokens), r.rid
+    calls = ex.metrics.counter("repro.decode.calls").value
+    assert calls > 0
+    assert ex.metrics.counter("repro.moe.chosen_expert_calls").value == (
+        calls if chosen else 0)

@@ -114,3 +114,30 @@ def test_quantiser_compiles_for_v5e(one_chip, kernel):
                  jax.ShapeDtypeStruct((QUANT_N // kq.QBLOCK,), jnp.float32,
                                       sharding=one_chip))
     assert _compile_has_kernel(fn, *specs)
+
+
+def test_decode_moe_reads_chosen_experts_on_v5e(one_chip, monkeypatch):
+    """DeepSeek-V2-Lite's decode MoE for one token (16 of 64 experts held,
+    26 layers stacked) compiles to the chosen-expert kernel, and no value
+    of the compiled program has a layer's whole held expert set."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import layers as L
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)  # the chip's path
+    cfg = get_config("deepseek_v2_lite_16b")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe,
+                                                           n_held=16))
+    m, D, n = cfg.moe, cfg.d_model, cfg.n_layers - cfg.first_k_dense
+    p = jax.eval_shape(lambda: L.init_moe(jax.random.PRNGKey(0), cfg))
+    spec = lambda a, lead=(): jax.ShapeDtypeStruct(lead + a.shape, a.dtype,
+                                                   sharding=one_chip)
+    p = jax.tree.map(spec, p)
+    p.update({k: spec(p[k], (n,)) for k in L.EXPERT_KEYS})  # the stacked run
+    x = jax.ShapeDtypeStruct((1, 1, D), jnp.bfloat16, sharding=one_chip)
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    txt = jax.jit(lambda p, x, layer: L.moe_fwd(p, cfg, x, layer=layer)
+                  ).lower(p, x, layer).compile().as_text()
+    assert "tpu_custom_call" in txt
+    assert f"bf16[{m.held},{D},{m.d_ff_expert}]" not in txt
